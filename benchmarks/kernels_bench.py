@@ -1,8 +1,9 @@
 """Kernel microbenchmarks: us_per_call for the Pallas kernels vs their jnp
-references.  NOTE: on this CPU container the kernels run in interpret mode
-(Python emulation), so absolute Pallas numbers are NOT hardware-representative
-— the jnp reference timing and the derived FLOP counts are the meaningful
-columns; on a real TPU the same harness times the Mosaic kernels.
+references.  On a TPU backend the kernels compile through Mosaic and the
+rows are named ``*_pallas_mosaic``; on any other backend they run under the
+Pallas interpreter (Python emulation), the rows are named
+``*_pallas_interp``, and their times say nothing about any chip — only the
+derived FLOP counts carry over.  The first output line names the device.
 
 Every row is REGISTERED first and the whole set is warmed before any timing
 begins: a shape that first compiles inside a timed region poisons not just
@@ -45,11 +46,16 @@ def timeit(fn, *args, iters: int = 3):
 def main(emit=print):
     key = jax.random.key(0)
     rows = []
+    dev = jax.devices()[0]
+    emit(f"# device: {dev.platform} {dev.device_kind} x{len(jax.devices())}")
+    interp = jax.default_backend() != "tpu"
+    tier = "interp" if interp else "mosaic"
 
     def add(name, fn, args, derived):
         """derived: callable us -> trailing CSV field (flop counts are
         static strings; achieved-rate fields need the measured time)."""
-        rows.append((name, fn, args, derived))
+        rows.append((name.replace("_pallas_interp", f"_pallas_{tier}"),
+                     fn, args, derived))
 
     # lora_matmul: (m,k,n,r) = (1024, 1024, 1024, 64)
     m, k, n, r = 1024, 1024, 1024, 64
@@ -84,7 +90,7 @@ def main(emit=print):
         add(f"lora_matmul_{mode}_pallas_interp",
             lambda x_, a_, b_, q=q, bits=bits: lora_matmul_quant_vjp(
                 x_, q.data, q.scales, a_, b_, 2.0, bits=bits,
-                interpret=True), (x, a, b),
+                interpret=interp), (x, a, b),
             lambda us, wb=wbytes: f"w_bytes={wb}_vs_fp={w.nbytes}")
 
     # lora_matmul backward: fused custom-VJP kernels vs jnp autodiff.
@@ -100,7 +106,7 @@ def main(emit=print):
     add("lora_matmul_bwd_pallas_interp",
         jax.jit(jax.grad(
             lambda x_, a_, b_: fused_lora_apply(x_, w, a_, b_, 2.0,
-                                                interpret=True).sum(),
+                                                interpret=interp).sum(),
             argnums=(0, 1, 2))), (x, a, b),
         lambda us, f=bwd_flops: f"flops={f}")
 
@@ -118,7 +124,7 @@ def main(emit=print):
     add("bgmv_matmul_ref_einsum", bgmv_ref, (xb, w, ab, bb, ids),
         lambda us, f=bflops: f"gflops={f/us/1e3:.2f}")
     add("bgmv_matmul_pallas_interp",
-        lambda *t: bgmv_matmul(*t, interpret=True), (xb, w, ab, bb, ids),
+        lambda *t: bgmv_matmul(*t, interpret=interp), (xb, w, ab, bb, ids),
         lambda us, f=bflops: f"flops={f}")
     # decode shape: one token per request (the GEMV-form kernel)
     x1 = xb[:, :1]
@@ -126,7 +132,7 @@ def main(emit=print):
     add("bgmv_gemv_ref_einsum", bgmv_ref, (x1, w, ab, bb, ids),
         lambda us, f=flops1: f"gflops={f/us/1e3:.2f}")
     add("bgmv_gemv_pallas_interp",
-        lambda x_, *t: bgmv_gemv(x_[:, 0], *t, interpret=True),
+        lambda x_, *t: bgmv_gemv(x_[:, 0], *t, interpret=interp),
         (x1, w, ab, bb, ids), lambda us, f=flops1: f"flops={f}")
     # quantized-base BGMV (decode is where packed bytes pay: the base GEMM
     # is the bandwidth term at batch-1 token shapes)
@@ -135,12 +141,12 @@ def main(emit=print):
         add(f"bgmv_matmul_{mode}_pallas_interp",
             lambda x_, a_, b_, i_, q=q, bits=bits: bgmv_matmul_quant(
                 x_, q.data, q.scales, a_, b_, i_, bits=bits,
-                interpret=True), (xb, ab, bb, ids),
+                interpret=interp), (xb, ab, bb, ids),
             lambda us, wb=q.nbytes: f"w_bytes={wb}_vs_fp={w.nbytes}")
         add(f"bgmv_gemv_{mode}_pallas_interp",
             lambda x_, a_, b_, i_, q=q, bits=bits: bgmv_gemv_quant(
                 x_[:, 0], q.data, q.scales, a_, b_, i_, bits=bits,
-                interpret=True), (x1, ab, bb, ids),
+                interpret=interp), (x1, ab, bb, ids),
             lambda us, wb=q.nbytes: f"w_bytes={wb}_vs_fp={w.nbytes}")
 
     # flash attention: b=1, s=1024, h=4, d=64

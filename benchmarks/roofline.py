@@ -6,7 +6,7 @@ Per (arch x shape x mesh):
   collective term = collective_bytes(per-device) / ICI_bw
 plus MODEL_FLOPS = 6*N*D (train) / 2*N*D (prefill) / 2*N_active*B (decode),
 the useful-compute ratio, the dominant bottleneck, and a what-would-move-it
-note.  Hardware: TPU v5e — 197 TF/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+note.  Hardware: the dry run's target chip, peaks from launch/peaks.py.
 
 The XLA cost/memory analyses of an SPMD module are for the per-device
 partitioned program, so no extra division by chip count is needed; chips
@@ -19,7 +19,7 @@ import json
 import os
 
 from repro.configs import INPUT_SHAPES, config_for_shape
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.peaks import peaks
 
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "EXPERIMENTS",
                           "dryrun")
@@ -69,10 +69,11 @@ def analyze(rec, devices=None):
         return None
     devices = devices or rec["devices"]
     src = rec.get("corrected", rec)   # unit-calibrated loop-exact stats
-    ct = (src["flops"] or 0) / PEAK_FLOPS_BF16
-    mt = (src["bytes_accessed"] or 0) / HBM_BW
+    pk = peaks()
+    ct = (src["flops"] or 0) / pk["flops_bf16"]
+    mt = (src["bytes_accessed"] or 0) / pk["hbm_bw"]
     cb = sum(src["collective_bytes"].values())
-    lt = cb / ICI_BW
+    lt = cb / pk["ici_bw"]
     terms = {"compute": ct, "memory": mt, "collective": lt}
     dom = max(terms, key=terms.get)
     mf = model_flops(rec["arch"], rec["shape"])

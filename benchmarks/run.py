@@ -107,15 +107,18 @@ def main() -> None:
     fast = os.environ.get("FAST", "0") not in ("0", "")
     rounds = 10 if fast else None
 
+    failed = []
+
     def run(name, fn, **kw):
         t0 = time.monotonic()
         print(f"# === {name} ===", flush=True)
         try:
             fn(**kw)
-        except Exception as e:  # keep the suite alive
+        except Exception as e:  # run the other suites, then exit non-zero
             import traceback
             print(f"{name},ERROR,{e}")
             traceback.print_exc()
+            failed.append(name)
         print(f"# === {name} done in {time.monotonic()-t0:.1f}s ===", flush=True)
 
     if "comm" in want:
@@ -161,6 +164,8 @@ def main() -> None:
             **({"rounds": rounds} if rounds else {}))
     if "table" in want:
         run("trajectory", trajectory)
+    if failed:
+        sys.exit(f"benchmark suites failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -42,6 +42,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.aggregation import get_strategy
 from repro.core.quant import dequantize_tree, has_quantized
@@ -367,6 +368,22 @@ def make_fed_round_step(model, *, strategy, opt_cfg, donate: bool = True,
     return jax.jit(round_step, donate_argnums=(1, 2) if donate else ())
 
 
+def _pin_to_mesh(adapters, opt_N):
+    """Hold the chunk's outgoing client state to the placement it came in
+    with (client dim over the client axes) when traced under a mesh.  Left
+    free, the partitioner may hand back a replicated leaf (FedSA's shared A
+    on a TPU 2x2), and the next chunk's new input sharding recompiles."""
+    from repro.sharding import rules
+    from repro.sharding.specs import current_mesh
+    mesh = current_mesh()
+    if mesh is None:
+        return adapters, opt_N
+    wsc = jax.lax.with_sharding_constraint
+    lora = wsc(adapters.lora, rules.lora_sharding(adapters.lora, mesh))
+    opt_N = wsc(opt_N, rules.lora_sharding(opt_N, mesh))
+    return dataclasses.replace(adapters, lora=lora), opt_N
+
+
 def make_run_chunk(model, *, strategy, opt_cfg, participation: float = 1.0,
                    batch_fn=None, client_weights=None,
                    donate: bool = True, jit: bool = True,
@@ -494,9 +511,11 @@ def make_run_chunk(model, *, strategy, opt_cfg, participation: float = 1.0,
                       async_state["rho"])
             (adapters, opt_N, key, tau, rho), ms = jax.lax.scan(
                 scan_step, carry0, xs)
+            adapters, opt_N = _pin_to_mesh(adapters, opt_N)
             return adapters, opt_N, key, {"tau": tau, "rho": rho}, ms
         (adapters, opt_N, key), ms = jax.lax.scan(
             scan_step, (adapters, opt_N, key), xs)
+        adapters, opt_N = _pin_to_mesh(adapters, opt_N)
         return adapters, opt_N, key, ms
 
     if not jit:
@@ -615,7 +634,20 @@ class FederatedTrainer:
         self.lora_cfg = lora_cfg      # reflects the padded rank when het
         key = jax.random.key(seed)
         kb, kl = jax.random.split(key)
-        self.base = base_params if base_params is not None else model.init(kb)
+        if base_params is not None:
+            self.base = base_params
+        elif mesh is not None:
+            # initialize straight into the mesh placement: an eager init
+            # materializes the whole base on one device before resharding,
+            # which a base larger than one chip's HBM cannot survive
+            from repro.sharding import rules
+            shapes = jax.eval_shape(model.init, kb)
+            # lint: disable=R2 -- runs once per trainer, not per step; the out_shardings belong to this trainer's mesh
+            self.base = jax.jit(
+                model.init,
+                out_shardings=rules.params_sharding(shapes, mesh))(kb)
+        else:
+            self.base = model.init(kb)
         lora1 = init_lora(self.base, kl, lora_cfg,
                           targets=lora_cfg.targets)
         # FedSA init: all clients start from the SAME A (and B=0)
@@ -741,6 +773,12 @@ class FederatedTrainer:
                                    rules.lora_sharding(self.lora, mesh))
         self.opt_state = jax.device_put(
             self.opt_state, rules.lora_sharding(self.opt_state, mesh))
+        # the scan key and async counters come back from a chunk replicated
+        # over the mesh: start them there, or the second chunk recompiles
+        rep = NamedSharding(mesh, P())
+        self._key = jax.device_put(self._key, rep)
+        if self.async_state is not None:
+            self.async_state = jax.device_put(self.async_state, rep)
 
     def _mesh_scope(self):
         if self.mesh is None:

@@ -25,13 +25,13 @@ adapter zero-padded to r_max (``AdapterBank.from_sets``), and zero rank
 rows/columns contribute nothing to either rank-r GEMM — mixed-rank banks run
 the same kernel at the same cost as uniform-rank ones, no mask multiplies.
 
-Two entry points share the structure:
+Two entry points share one kernel:
 
   ``bgmv_matmul``  x (B, s, k) — prefill / full-sequence forward, one
                    (s, k) block row per request
-  ``bgmv_gemv``    x (B, k)    — single-token decode, the m=1 GEMV shape
-                   served directly instead of round-tripping through the
-                   2-D sublane-padding path
+  ``bgmv_gemv``    x (B, k)    — single-token decode, run as (B, 1, k) with
+                   the unit s dim unpadded (a block equal to the full dim is
+                   Mosaic-legal; a (1, bk) block of a 2-D (B, k) is not)
 
 The bank is gamma-free: registration folds every tenant's scaling factor
 into its B (``AdapterSet.fold_gamma``), so these kernels have no gamma
@@ -111,60 +111,6 @@ def _bgmv_call(x, w, a, b, ids, *, bn, bk, interpret):
     )(ids, x, w, a, b)
 
 
-def _bgmv_gemv_kernel(ids_ref, x_ref, w_ref, a_ref, b_ref, out_ref, p_ref, *,
-                      nk):
-    """GEMV shape: one (1, k) token row per request, no s dim anywhere."""
-    del ids_ref
-    n = pl.program_id(1)
-    k = pl.program_id(2)
-
-    @pl.when((n == 0) & (k == 0))
-    def _init_p():
-        p_ref[...] = jnp.zeros_like(p_ref)
-
-    @pl.when(k == 0)
-    def _init_out():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    xb = x_ref[...].astype(jnp.float32)         # (1, bk)
-
-    @pl.when(n == 0)
-    def _acc_p():
-        p_ref[...] += xb @ a_ref[0].astype(jnp.float32).T
-
-    out_ref[...] += xb @ w_ref[...].astype(jnp.float32)
-
-    @pl.when(k == nk - 1)
-    def _apply_lora():
-        out_ref[...] += p_ref[...] @ b_ref[0].astype(jnp.float32).T
-
-
-def _bgmv_gemv_call(x, w, a, b, ids, *, bn, bk, interpret):
-    """x (B, k) padded -> (B, n) fp32; one grid row per request."""
-    bsz, kdim = x.shape
-    n = w.shape[1]
-    r = a.shape[1]
-    nn, nk = n // bn, kdim // bk
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bsz, nn, nk),
-        in_specs=[
-            pl.BlockSpec((1, bk), lambda i, j, k, ids: (i, k)),          # x
-            pl.BlockSpec((bk, bn), lambda i, j, k, ids: (k, j)),         # w
-            pl.BlockSpec((1, r, bk), lambda i, j, k, ids: (ids[i], 0, k)),
-            pl.BlockSpec((1, bn, r), lambda i, j, k, ids: (ids[i], j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bn), lambda i, j, k, ids: (i, j)),
-        scratch_shapes=[pltpu.VMEM((1, r), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_bgmv_gemv_kernel, nk=nk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, n), jnp.float32),
-        interpret=interpret,
-    )(ids, x, w, a, b)
-
-
 # ------------------------------------------------------------------ wrappers
 
 def _pad_operands(w, a, b, kdim, n, r):
@@ -199,8 +145,10 @@ def bgmv_matmul(x, w, a, b, ids, *, interpret: bool = False):
 
 
 def bgmv_gemv(x, w, a, b, ids, *, interpret: bool = False):
-    """Single-token variant: x (B, k) -> (B, n) fp32, the decode GEMV shape
-    served without an s dim or sublane padding of the request rows."""
+    """Single-token variant: x (B, k) -> (B, n) fp32, the decode GEMV shape.
+    Runs the matmul kernel on x as (B, 1, k) with the unit s dim left
+    unpadded: a (1, bk) block equals the array's full s dim, which Mosaic
+    accepts, so no request row pads to a sublane multiple."""
     bsz, kdim = x.shape
     n = w.shape[1]
     r = a.shape[1]
@@ -208,7 +156,8 @@ def bgmv_gemv(x, w, a, b, ids, *, interpret: bool = False):
     if kp != kdim:
         x = jnp.pad(x, ((0, 0), (0, kp - kdim)))
     ids = jnp.asarray(ids, jnp.int32)
-    y = _bgmv_gemv_call(x, w, a, b, ids, bn=bn, bk=bk, interpret=interpret)
+    y = _bgmv_call(x[:, None], w, a, b, ids, bn=bn, bk=bk,
+                   interpret=interpret)[:, 0]
     if np_ != n:
         y = y[:, :n]
     return y
@@ -248,33 +197,6 @@ def _bgmv_kernel_q(ids_ref, x_ref, wd_ref, ws_ref, a_ref, b_ref, out_ref,
         out_ref[0] += p_ref[...] @ b_ref[0].astype(jnp.float32).T
 
 
-def _bgmv_gemv_kernel_q(ids_ref, x_ref, wd_ref, ws_ref, a_ref, b_ref,
-                        out_ref, p_ref, *, nk, bits):
-    del ids_ref
-    n = pl.program_id(1)
-    k = pl.program_id(2)
-
-    @pl.when((n == 0) & (k == 0))
-    def _init_p():
-        p_ref[...] = jnp.zeros_like(p_ref)
-
-    @pl.when(k == 0)
-    def _init_out():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    xb = x_ref[...].astype(jnp.float32)
-
-    @pl.when(n == 0)
-    def _acc_p():
-        p_ref[...] += xb @ a_ref[0].astype(jnp.float32).T
-
-    out_ref[...] += xb @ dequant_block(wd_ref[...], ws_ref[...], bits)
-
-    @pl.when(k == nk - 1)
-    def _apply_lora():
-        out_ref[...] += p_ref[...] @ b_ref[0].astype(jnp.float32).T
-
-
 def _pad_quant_operands(wd, ws, a, b, bits, kdim, n, r):
     """Packed-base twin of :func:`_pad_operands`: data rows pad to kp (int8)
     or kp/2 (int4 nibble pairs), scale rows to 1 / kp/G; zero scales make
@@ -295,6 +217,37 @@ def _pad_quant_operands(wd, ws, a, b, bits, kdim, n, r):
     return wd, ws, a, b, bn, bk, kp, np_
 
 
+def _bgmv_quant_call(x, wd, ws, a, b, ids, *, bits, bn, bk, interpret):
+    """x (B, s, k) padded, wd/ws padded per :func:`_pad_quant_operands`,
+    a (K, r, k), b (K, n, r), ids (B,) int32 -> (B, s, n) fp32."""
+    bsz, s, kp = x.shape
+    np_ = wd.shape[-1]
+    r = a.shape[1]
+    gsize = 0 if bits == 8 else kp // ws.shape[-2]
+    bwd, bws = _quant_w_shapes(bits, gsize, bk, bn)
+    nn, nk = np_ // bn, kp // bk
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(bsz, nn, nk),
+        in_specs=[
+            pl.BlockSpec((1, s, bk), lambda i, j, k, ids: (i, 0, k)),
+            pl.BlockSpec(bwd, lambda i, j, k, ids: (k, j)),
+            (pl.BlockSpec(bws, lambda i, j, k, ids: (0, j)) if bits == 8
+             else pl.BlockSpec(bws, lambda i, j, k, ids: (k, j))),
+            pl.BlockSpec((1, r, bk), lambda i, j, k, ids: (ids[i], 0, k)),
+            pl.BlockSpec((1, bn, r), lambda i, j, k, ids: (ids[i], j, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, s, bn), lambda i, j, k, ids: (i, 0, j)),
+        scratch_shapes=[pltpu.VMEM((s, r), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_bgmv_kernel_q, nk=nk, bits=bits),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bsz, s, np_), jnp.float32),
+        interpret=interpret,
+    )(ids, x, wd, ws, a, b)
+
+
 def bgmv_matmul_quant(x, wd, ws, a, b, ids, *, bits, interpret: bool = False):
     """:func:`bgmv_matmul` over a packed base: x (B, s, k), wd/ws per
     ``dequant_block``, a (K, r, k), b (K, n, r), ids (B,) -> (B, s, n)."""
@@ -303,73 +256,30 @@ def bgmv_matmul_quant(x, wd, ws, a, b, ids, *, bits, interpret: bool = False):
     r = a.shape[1]
     wd, ws, a, b, bn, bk, kp, np_ = _pad_quant_operands(
         wd, ws, a, b, bits, kdim, n, r)
-    r = a.shape[1]
     sp = round_up(s, SUBLANE)
     if sp != s or kp != kdim:
         x = jnp.pad(x, ((0, 0), (0, sp - s), (0, kp - kdim)))
     ids = jnp.asarray(ids, jnp.int32)
-    gsize = 0 if bits == 8 else kp // ws.shape[-2]
-    bwd, bws = _quant_w_shapes(bits, gsize, bk, bn)
-    nn, nk = np_ // bn, kp // bk
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bsz, nn, nk),
-        in_specs=[
-            pl.BlockSpec((1, sp, bk), lambda i, j, k, ids: (i, 0, k)),
-            pl.BlockSpec(bwd, lambda i, j, k, ids: (k, j)),
-            (pl.BlockSpec(bws, lambda i, j, k, ids: (0, j)) if bits == 8
-             else pl.BlockSpec(bws, lambda i, j, k, ids: (k, j))),
-            pl.BlockSpec((1, r, bk), lambda i, j, k, ids: (ids[i], 0, k)),
-            pl.BlockSpec((1, bn, r), lambda i, j, k, ids: (ids[i], j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, sp, bn), lambda i, j, k, ids: (i, 0, j)),
-        scratch_shapes=[pltpu.VMEM((sp, a.shape[1]), jnp.float32)],
-    )
-    y = pl.pallas_call(
-        functools.partial(_bgmv_kernel_q, nk=nk, bits=bits),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, sp, np_), jnp.float32),
-        interpret=interpret,
-    )(ids, x, wd, ws, a, b)
+    y = _bgmv_quant_call(x, wd, ws, a, b, ids, bits=bits, bn=bn, bk=bk,
+                         interpret=interpret)
     if sp != s or np_ != n:
         y = y[:, :s, :n]
     return y
 
 
 def bgmv_gemv_quant(x, wd, ws, a, b, ids, *, bits, interpret: bool = False):
-    """Single-token packed-base variant: x (B, k) -> (B, n) fp32."""
+    """Single-token packed-base variant: x (B, k) -> (B, n) fp32, run as
+    (B, 1, k) through the matmul kernel like :func:`bgmv_gemv`."""
     bsz, kdim = x.shape
     n = wd.shape[-1]
     r = a.shape[1]
     wd, ws, a, b, bn, bk, kp, np_ = _pad_quant_operands(
         wd, ws, a, b, bits, kdim, n, r)
-    r = a.shape[1]
     if kp != kdim:
         x = jnp.pad(x, ((0, 0), (0, kp - kdim)))
     ids = jnp.asarray(ids, jnp.int32)
-    gsize = 0 if bits == 8 else kp // ws.shape[-2]
-    bwd, bws = _quant_w_shapes(bits, gsize, bk, bn)
-    nn, nk = np_ // bn, kp // bk
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bsz, nn, nk),
-        in_specs=[
-            pl.BlockSpec((1, bk), lambda i, j, k, ids: (i, k)),
-            pl.BlockSpec(bwd, lambda i, j, k, ids: (k, j)),
-            (pl.BlockSpec(bws, lambda i, j, k, ids: (0, j)) if bits == 8
-             else pl.BlockSpec(bws, lambda i, j, k, ids: (k, j))),
-            pl.BlockSpec((1, r, bk), lambda i, j, k, ids: (ids[i], 0, k)),
-            pl.BlockSpec((1, bn, r), lambda i, j, k, ids: (ids[i], j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bn), lambda i, j, k, ids: (i, j)),
-        scratch_shapes=[pltpu.VMEM((1, a.shape[1]), jnp.float32)],
-    )
-    y = pl.pallas_call(
-        functools.partial(_bgmv_gemv_kernel_q, nk=nk, bits=bits),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, np_), jnp.float32),
-        interpret=interpret,
-    )(ids, x, wd, ws, a, b)
+    y = _bgmv_quant_call(x[:, None], wd, ws, a, b, ids, bits=bits, bn=bn,
+                         bk=bk, interpret=interpret)[:, 0]
     if np_ != n:
         y = y[:, :n]
     return y

@@ -1,13 +1,12 @@
 """Jit'd wrappers over the Pallas kernels — the public kernel API.
 
-On CPU containers the kernels run with interpret=True (Python emulation);
-on a real TPU, set ``REPRO_KERNEL_INTERPRET=0`` (or rely on the default
-platform detection) to execute the compiled Mosaic kernels.
+On a TPU backend the kernels compile through Mosaic.  On any other backend
+they run under the Pallas interpreter (Python emulation: the kernels'
+numerics at Python speed, for tests and debugging — never a timing).
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -18,9 +17,6 @@ from repro.kernels.rglru_scan import rglru_scan_pallas
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_KERNEL_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false")
     return jax.default_backend() != "tpu"
 
 

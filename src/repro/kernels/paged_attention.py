@@ -63,7 +63,7 @@ def _paged_attn_kernel(table_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref,
     q = q_ref[...].astype(jnp.float32).reshape(kh, g, hd)      # (kh, g, hd)
     k = k_ref[0].astype(jnp.float32)                           # (bs, kh, hd)
     v = v_ref[0].astype(jnp.float32)
-    pos = pos_ref[0]                                           # (bs,)
+    pos = pos_ref[0]                                           # (1, bs)
 
     scores = jnp.einsum("kgd,skd->kgs", q, k) * hd ** -0.5
     if softcap:
@@ -71,7 +71,7 @@ def _paged_attn_kernel(table_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref,
     valid = (pos >= 0) & (pos <= qp)
     if window is not None:
         valid &= qp - pos < window
-    scores = jnp.where(valid[None, None, :], scores, NEG_INF)
+    scores = jnp.where(valid[None], scores, NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1))
@@ -111,8 +111,8 @@ def paged_attention(q, k_pool, v_pool, pos_pool, table, qpos, *, window=None,
                          lambda i, j, table, qpos: (table[i, j], 0, 0, 0)),
             pl.BlockSpec((1, bs, kh, hd),
                          lambda i, j, table, qpos: (table[i, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs),
-                         lambda i, j, table, qpos: (table[i, j], 0)),
+            pl.BlockSpec((1, 1, bs),
+                         lambda i, j, table, qpos: (table[i, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, h, hd), lambda i, j, table, qpos: (i, 0, 0)),
         scratch_shapes=[pltpu.VMEM((kh, g, hd), jnp.float32),
@@ -126,4 +126,4 @@ def paged_attention(q, k_pool, v_pool, pos_pool, table, qpos, *, window=None,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
         interpret=interpret,
     )(jnp.asarray(table, jnp.int32), jnp.asarray(qpos, jnp.int32),
-      q, k_pool, v_pool, pos_pool)
+      q, k_pool, v_pool, pos_pool.reshape(-1, 1, bs))

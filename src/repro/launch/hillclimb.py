@@ -18,15 +18,17 @@ import argparse
 import json
 
 from repro.launch import dryrun
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.peaks import peaks
 from repro.sharding import opts
 
 
 def terms(rec):
     src = rec.get("corrected", rec)
-    return {"compute_s": src["flops"] / PEAK_FLOPS_BF16,
-            "memory_s": src["bytes_accessed"] / HBM_BW,
-            "collective_s": sum(src["collective_bytes"].values()) / ICI_BW,
+    pk = peaks()
+    return {"compute_s": src["flops"] / pk["flops_bf16"],
+            "memory_s": src["bytes_accessed"] / pk["hbm_bw"],
+            "collective_s": (sum(src["collective_bytes"].values())
+                             / pk["ici_bw"]),
             "temp_gb": rec.get("temp_size_in_bytes", 0) / 1e9}
 
 

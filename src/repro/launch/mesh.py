@@ -5,27 +5,32 @@ Multi-pod  : (2, 16, 16) axes ("pod", "data", "model")    = 512 chips
 
 Defined as a FUNCTION so importing this module never touches jax device
 state (the dry-run sets XLA_FLAGS *before* any jax import).
+
+Every mesh is built with ``AxisType.Auto`` axes: the model code places
+arrays with ``with_sharding_constraint`` and lets the partitioner propagate
+the rest, which Explicit axes (``jax.make_mesh``'s default) refuse at the
+first gather whose output sharding is ambiguous (the embedding lookup).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-# v5e hardware constants for the roofline model (per chip)
-PEAK_FLOPS_BF16 = 197e12       # FLOP/s
-HBM_BW = 819e9                 # B/s
-ICI_BW = 50e9                  # B/s per link
+
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for multi-device unit tests (run in subprocesses with
     --xla_force_host_platform_device_count)."""
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def mesh_from_spec(spec: str):
@@ -46,7 +51,7 @@ def mesh_from_spec(spec: str):
             3: ("pod", "data", "model")}.get(len(dims))
     if axes is None:
         raise ValueError(f"mesh spec '{spec}': expected 1-3 'x'-joined dims")
-    return jax.make_mesh(dims, axes)
+    return _mesh(dims, axes)
 
 
 def client_axes(mesh) -> tuple:

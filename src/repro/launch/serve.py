@@ -48,6 +48,7 @@ from repro.core.lora import (AdapterBank, AdapterSet, LiveAdapterBank,
 from repro.core.quant import (apply_quant_flag, dequantize_tree,
                               has_quantized, requantize_merged)
 from repro.kernels import dispatch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
 from repro.models.transformer import (merge_paged_cache, paged_prefill_view,
                                       reset_paged_blocks)
@@ -792,6 +793,7 @@ def main(argv=None):
                          "tenants overflow to host RAM and are LRU-promoted "
                          "on demand (0 = whole bank on device, no overflow)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

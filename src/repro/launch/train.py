@@ -1,8 +1,13 @@
 """Federated LoRA fine-tuning launcher.
 
-CPU-scale (this container):
+Reduced width, on any backend (the Pallas kernels are not used unless the
+model config sets ``use_pallas``):
   PYTHONPATH=src python -m repro.launch.train --arch gemma-2b --reduced \
       --rank 64 --scaling sfedlora --clients 4 --rounds 30 --chunk-rounds 10
+
+Full published width fits one TPU v5e at a short sequence (see
+chip_smoke.py): ... --arch gemma-2b --clients 4 --rank 64 --seq 128 \
+      --batch-per-client 1 --local-steps 1
 
 On a mesh the same entry point shards the client dim over the mesh's client
 axes ("pod","data") and runs the compiled scan engine:
@@ -24,6 +29,7 @@ from repro.core.aggregation import STRATEGIES
 from repro.core.federated import FederatedTrainer
 from repro.core.quant import apply_quant_flag, quantize_tree
 from repro.data.synthetic import FederatedDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import mesh_from_spec
 from repro.models.api import build_model
 
@@ -106,6 +112,7 @@ def main(argv=None):
                     help="checkpoint to restore (incl. PRNG key + round, so "
                          "the run continues bit-exactly)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

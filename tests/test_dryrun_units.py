@@ -114,3 +114,34 @@ def test_param_spec_rules():
     assert param_spec(("stack", "tail", "t0", "mlp", "w_down"),
                       (14336, 5120), m) == P("model", None)
     assert param_spec(("final_scale",), (5120,), m) == P(None)
+
+
+@pytest.mark.parametrize("env,want_repo", [({}, True),
+                                           ({"JAX_COMPILATION_CACHE_DIR":
+                                             "/elsewhere"}, False)])
+def test_compile_cache_placement(env, want_repo):
+    """The variable, when set, wins and the program sets nothing; otherwise
+    the fixed repo-local directory (never a temp name)."""
+    from repro.launch.compile_cache import REPO_CACHE, cache_dir
+    got = cache_dir(env)
+    assert got == (REPO_CACHE if want_repo else None)
+    assert REPO_CACHE.name == ".jax_cache"
+    assert (REPO_CACHE.parent / "src" / "repro").is_dir()
+
+
+def test_peaks_table_rejects_unknown_device():
+    from repro.launch.peaks import TARGET_KIND, peaks
+    assert peaks(TARGET_KIND)["flops_bf16"] == 197e12
+    assert peaks()["hbm_bw"] == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks("cpu")
+
+
+@pytest.mark.parametrize("spec", ["1", "1x1", "1x1x1"])
+def test_meshes_use_auto_axes(spec):
+    """Explicit axes (JAX 0.9's make_mesh default) reject the model's
+    embedding gather; every mesh the launchers build is Auto."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import mesh_from_spec
+    mesh = mesh_from_spec(spec)
+    assert all(t == AxisType.Auto for t in mesh.axis_types)

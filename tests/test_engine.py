@@ -366,9 +366,29 @@ ok = all(np.allclose(np.asarray(x), np.asarray(y), rtol=1e-6, atol=1e-7)
          for x, y in zip(jax.tree.leaves(ref.lora), jax.tree.leaves(tr.lora)))
 a_shard = str(jax.tree.leaves(tr.lora)[0].sharding.spec)
 dev = make(mesh, "device"); dev.run(3)      # on-device data on the mesh
+compiles = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda ev, secs, fun_name="", **kw: compiles.append(fun_name)
+    if ev == "/jax/core/compile/backend_compile_duration" else None)
+dev.run(3)      # the second chunk takes the first's outputs: no recompile
+# no base given: the trainer initializes the base straight into its mesh
+# placement (one jitted init; the one-device init is eager, and XLA may
+# round a fused scale differently by an ulp)
+def fresh(m):
+    ds = FederatedDataset(64, 4, seq_len=32, batch_per_client=2, seed=0)
+    return FederatedTrainer(model, ds, lora_cfg=LoRAConfig(rank=8),
+        fed_cfg=FederatedConfig(num_clients=4), opt_cfg=OptimizerConfig(),
+        mesh=m).base
+b1, bm = fresh(None), fresh(mesh)
+base_ok = all(np.allclose(np.asarray(x), np.asarray(y), rtol=1e-6, atol=0)
+              for x, y in zip(jax.tree.leaves(b1), jax.tree.leaves(bm)))
+embed_spec = str(bm["embed"].sharding.spec)
 print(json.dumps({"match": bool(ok), "a_spec": a_shard,
                   "dev_loss_finite": bool(np.isfinite(
-                      dev.history[-1]["loss"]))}))
+                      dev.history[-1]["loss"])),
+                  "chunk_recompiles": compiles.count("jit(run_chunk)"),
+                  "base_init_match": bool(base_ok),
+                  "embed_spec": embed_spec}))
 """
 
 
@@ -390,6 +410,8 @@ def test_trainer_on_mesh_matches_single_device(tmp_path):
     assert rec["match"], rec
     assert "data" in rec["a_spec"], rec
     assert rec["dev_loss_finite"], rec
+    assert rec["chunk_recompiles"] == 0, rec
+    assert rec["base_init_match"] and "model" in rec["embed_spec"], rec
 
 
 def test_engine_history_and_metrics_format(tiny):
