@@ -1,0 +1,132 @@
+#!/usr/bin/env python3
+"""Compile a cell's hot programs at full size for a described TPU v5e, with
+no chip attached, and print each program's memory analysis.
+
+  JAX_PLATFORMS=cpu python3 bench/rehearse.py --workload <cell>
+
+A training cell compiles its round engine (``make_run_chunk``, one chunk);
+a serving cell its largest admission prefill and its decode chunk.  The
+compiler refuses here what it would refuse on the chip (a program that does
+not fit, a block shape it cannot tile), at no chip time.  Nothing runs, so
+nothing here is a time.
+"""
+import argparse
+import json
+import os
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+sys.path[:0] = [os.path.join(ROOT, "src"), HERE]
+
+GB = 1e9
+
+
+def report(name, compiled):
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    print(f"{name}: arguments {m.argument_size_in_bytes / GB:.3f} GB, "
+          f"outputs {m.output_size_in_bytes / GB:.3f} GB, temporaries "
+          f"{m.temp_size_in_bytes / GB:.3f} GB, aliased "
+          f"{m.alias_size_in_bytes / GB:.3f} GB, total {total / GB:.3f} GB",
+          flush=True)
+
+
+def train_programs(cfg, mix, sharded):
+    import jax
+    import jax.numpy as jnp
+    from repro.configs.base import OptimizerConfig
+    from repro.core.federated import make_run_chunk
+    from repro.core.lora import AdapterSet
+    from repro.models.api import build_model
+    import model as bmodel
+    import traffic
+    model = build_model(bmodel.program_config(cfg))
+    n = mix["clients"]
+    params = sharded(jax.eval_shape(model.init, jax.random.key(0)))
+    lora = sharded(bmodel.program_lora(jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.float32),
+        bmodel.lora_shapes(cfg, mix["rank"], mix["targets"], lead=(n,)),
+        is_leaf=lambda x: isinstance(x, tuple))))
+    aset = AdapterSet(lora=lora, gamma=traffic.sfedlora_gamma(mix),
+                      rank=mix["rank"], alpha=mix["alpha"])
+    opt = sharded({"t": jax.ShapeDtypeStruct((n,), jnp.int32)})
+    key = sharded(jax.eval_shape(lambda: jax.random.key(0)))
+    round0 = sharded(jax.ShapeDtypeStruct((), jnp.int32))
+    batches = sharded({"tokens": jax.ShapeDtypeStruct(
+        (mix["chunk_rounds"], n, mix["local_steps"], mix["batch_per_client"],
+         mix["seq_len"]), jnp.int32)})
+    run_chunk = make_run_chunk(
+        model, strategy=mix["aggregation"],
+        opt_cfg=OptimizerConfig(name=mix["optimizer"], lr=mix["lr"]))
+    yield "run_chunk", run_chunk.lower(params, aset, opt, key, round0,
+                                       batches=batches)
+
+
+def serve_programs(cfg, mix, sharded):
+    import jax
+    import jax.numpy as jnp
+    from repro.core.lora import AdapterSet
+    from repro.launch import serve
+    from repro.models.api import build_model
+    import model as bmodel
+    model = build_model(bmodel.program_config(cfg))
+    b = mix["max_batch"]
+    mb = -(-(mix["prompt_len"] + mix["output"]["max"]) // mix["block_size"])
+    params = sharded(jax.eval_shape(model.init, jax.random.key(0)))
+    cache = sharded(jax.eval_shape(lambda: model.init_paged_cache(
+        1 + b * mb, mix["block_size"], b)))
+    lora = sharded(bmodel.program_lora(jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.float32),
+        bmodel.lora_shapes(cfg, mix["rank"], mix["targets"],
+                           lead=(mix["tenants"],)),
+        is_leaf=lambda x: isinstance(x, tuple))))
+    i32 = lambda *s: sharded(jax.ShapeDtypeStruct(s, jnp.int32))
+    # what AdapterBank.requests(ids) builds, without its host-side id check
+    adapters = AdapterSet(lora=lora, gamma=1.0, rank=mix["rank"],
+                          batched=True, ids=i32(b))
+    for g in sorted({1, b // 2, b}):
+        yield f"paged_admit[{g}]", serve._jit_paged_admit(model).lower(
+            params, cache, i32(g, mix["prompt_len"]), i32(g, mb), i32(g),
+            i32(g * mb), AdapterSet(lora=lora, gamma=1.0, rank=mix["rank"],
+                                    batched=True, ids=i32(g)))
+    yield "paged_chunk", serve._jit_paged_chunk(model).lower(
+        params, cache, i32(b, 1), i32(b),
+        sharded(jax.ShapeDtypeStruct((b,), jnp.bool_)), i32(b, mb), adapters,
+        steps=mix["chunk"])
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    args = ap.parse_args(argv)
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    import model as bmodel
+    import traffic
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        cell = {w["name"]: w for w in json.load(f)["workloads"]}[
+            args.workload]
+    cfg, mix = bmodel.load_config(cell["config"]), traffic.load(
+        cell["traffic"])
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    one = SingleDeviceSharding(topo.devices[0])
+    sharded = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), tree)
+    programs = train_programs if mix["kind"] == "train" else serve_programs
+    failed = 0
+    for name, lowered in programs(cfg, mix, sharded):
+        try:
+            report(name, lowered.compile())
+        except jax.errors.JaxRuntimeError as e:
+            failed += 1
+            print(f"{name}: refused: {str(e).splitlines()[0]}", flush=True)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
