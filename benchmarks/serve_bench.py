@@ -88,6 +88,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import bench_config
+from repro.analysis import trace
 from repro.analysis.sanitizers import RecompileGuard
 from repro.configs.base import LoRAConfig
 from repro.core.lora import AdapterBank, LiveAdapterBank, init_adapter_set
@@ -516,9 +517,9 @@ def main(steps: int = STEPS, ci: bool = False):
     for name, fns in variants.items():
         dispatches = {}
         for engine in ("compiled", "hostloop"):
-            serve.reset_dispatch_meter()
-            fns[engine]()
-            dispatches[engine] = serve.host_dispatches
+            with trace.tracing() as t:
+                fns[engine]()
+            dispatches[engine] = t.counters["serve.dispatches"]
         rows = _rows(best, name, PROMPT, steps, BATCH, dispatches)
         for engine, row in rows.items():
             results["engines"][engine][name] = row

@@ -22,11 +22,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import jax
 import jax.numpy as jnp
 
+from repro.analysis import trace
 from repro.checkpoint.io import load_adapter_state
 from repro.configs import get_config
 from repro.configs.base import FederatedConfig, LoRAConfig, OptimizerConfig
 from repro.core.lora import AdapterBank
-from repro.launch import serve
 from repro.launch.serve import generate, generate_banked
 from repro.models.api import build_model
 
@@ -73,11 +73,11 @@ print(f"bank: {bank.size} tenants, ranks {bank.ranks}, "
 # ---- multi-tenant: 4 requests, round-robin over the checkpointed clients
 prompt = jnp.asarray([[5, 17, 42, 7]] * 4, jnp.int32)
 ids = jnp.arange(4) % bank.size
-serve.reset_dispatch_meter()
-seq = generate_banked(model, base, bank, ids, prompt, steps=STEPS,
-                      max_len=4 + STEPS)
+with trace.tracing() as t:
+    seq = generate_banked(model, base, bank, ids, prompt, steps=STEPS,
+                          max_len=4 + STEPS)
 print(f"banked decode (adapter ids {list(map(int, ids))}, "
-      f"{serve.host_dispatches} host dispatch for {STEPS} tokens):")
+      f"{t.counters['serve.dispatches']} host dispatch for {STEPS} tokens):")
 print(seq)
 
 # personalization check: rows served by different tenants may diverge even

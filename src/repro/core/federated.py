@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.analysis import trace
 from repro.core.aggregation import get_strategy
 from repro.core.quant import dequantize_tree, has_quantized
 from repro.core.lora import (AdapterSet, apply_rank_mask, init_lora,
@@ -582,6 +583,13 @@ class FederatedTrainer:
     the dataset's per-client example counts (``dataset.size_weights``).
     With all ranks equal this path is bit-identical to the homogeneous
     engine (tests/test_conformance.py).
+
+    Under :func:`repro.analysis.trace.tracing` each chunk is a ``fed.chunk``
+    span (attrs ``rounds``, ``round0``) holding ``fed.stage`` (the
+    ``round_batch`` calls and their stack), ``fed.upload`` (the batches'
+    copy to the device), ``fed.call`` (the engine call, an enqueue) and
+    ``fed.sync`` (the wait for the chunk's metrics); the watchdog's host
+    copy is ``fed.snapshot``.  Counters: ``fed.rounds``, ``fed.retries``.
     """
 
     def __init__(self, model, dataset, *, lora_cfg, fed_cfg, opt_cfg,
@@ -791,13 +799,16 @@ class FederatedTrainer:
     def _stage_batches(self, num_rounds: int):
         """Host data for the next ``num_rounds`` rounds, stacked for the
         scan: leaves (num_rounds, N, local_steps, batch, seq)."""
-        nb = np.stack([self.dataset.round_batch(self.fed_cfg.local_steps)
-                       for _ in range(num_rounds)])
-        batches = {"tokens": jnp.asarray(nb)}
-        if self.mesh is not None:
-            from repro.sharding import rules
-            batches = jax.device_put(
-                batches, rules.chunked_inputs_sharding(batches, self.mesh))
+        with trace.span("fed.stage"):
+            nb = np.stack([self.dataset.round_batch(self.fed_cfg.local_steps)
+                           for _ in range(num_rounds)])
+        with trace.span("fed.upload"):
+            batches = {"tokens": jnp.asarray(nb)}
+            if self.mesh is not None:
+                from repro.sharding import rules
+                batches = jax.device_put(
+                    batches, rules.chunked_inputs_sharding(batches,
+                                                           self.mesh))
         return batches
 
     def _train_adapters(self) -> AdapterSet:
@@ -815,38 +826,43 @@ class FederatedTrainer:
         return dataclasses.replace(aset, gamma=g)
 
     def _run_one_chunk(self, num_rounds: int):
-        kwargs = {}
-        if self.data_mode == "device":
-            kwargs["num_rounds"] = num_rounds
-        else:
-            kwargs["batches"] = self._stage_batches(num_rounds)
-        with self._mesh_scope():
-            if self.async_mode:
-                (aset, self.opt_state, self._key, self.async_state,
-                 ms) = self._run_chunk(
-                    self.base, self._train_adapters(), self.opt_state,
-                    self._key, jnp.asarray(self.round_idx, jnp.int32),
-                    self.async_state, **kwargs)
-                self._rho_host = _quantize_rho(
-                    float(self.async_state["rho"]))
+        with trace.span("fed.chunk", rounds=num_rounds,
+                        round0=self.round_idx):
+            kwargs = {}
+            if self.data_mode == "device":
+                kwargs["num_rounds"] = num_rounds
             else:
-                aset, self.opt_state, self._key, ms = self._run_chunk(
-                    self.base, self.adapters, self.opt_state, self._key,
-                    jnp.asarray(self.round_idx, jnp.int32), **kwargs)
-        # only the A/B tree is engine state (gamma/rank mask are static
-        # config riding in the AdapterSet treedef — the trainer keeps its
-        # own uniform-rank mask even though the canonical AdapterSet form
-        # collapses an all-ones mask to None)
-        self.lora = aset.lora
-        ms = {k: np.asarray(v) for k, v in ms.items()}
-        out = []
-        for i in range(num_rounds):
-            self.round_idx += 1
-            m = {k: float(v[i]) for k, v in ms.items()}
-            m["round"] = self.round_idx
-            self.history.append(m)
-            out.append(m)
-        return out
+                kwargs["batches"] = self._stage_batches(num_rounds)
+            with self._mesh_scope(), trace.span("fed.call"):
+                if self.async_mode:
+                    (aset, self.opt_state, self._key, self.async_state,
+                     ms) = self._run_chunk(
+                        self.base, self._train_adapters(), self.opt_state,
+                        self._key, jnp.asarray(self.round_idx, jnp.int32),
+                        self.async_state, **kwargs)
+                else:
+                    aset, self.opt_state, self._key, ms = self._run_chunk(
+                        self.base, self.adapters, self.opt_state, self._key,
+                        jnp.asarray(self.round_idx, jnp.int32), **kwargs)
+            # only the A/B tree is engine state (gamma/rank mask are static
+            # config riding in the AdapterSet treedef — the trainer keeps its
+            # own uniform-rank mask even though the canonical AdapterSet form
+            # collapses an all-ones mask to None)
+            self.lora = aset.lora
+            with trace.span("fed.sync"):
+                if self.async_mode:
+                    self._rho_host = _quantize_rho(
+                        float(self.async_state["rho"]))
+                ms = {k: np.asarray(v) for k, v in ms.items()}
+            trace.count("fed.rounds", num_rounds)
+            out = []
+            for i in range(num_rounds):
+                self.round_idx += 1
+                m = {k: float(v[i]) for k, v in ms.items()}
+                m["round"] = self.round_idx
+                self.history.append(m)
+                out.append(m)
+            return out
 
     # ------------------------------------------------------------- watchdog
 
@@ -951,7 +967,8 @@ class FederatedTrainer:
         from repro.analysis.stability_check import ScalingCollapseError
         retries = 0
         while True:
-            snap = self._snapshot()
+            with trace.span("fed.snapshot"):
+                snap = self._snapshot()
             out = self._run_one_chunk(chunk)
             report = self._chunk_report(chunk)
             if report is None or report.ok:
@@ -964,6 +981,7 @@ class FederatedTrainer:
             self._rollback(snap)
             self._recover(report, retries)
             retries += 1
+            trace.count("fed.retries")
 
     # -------------------------------------------------------------- running
 

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis import trace
 from repro.analysis.hostcheck import check_adapter_ids
 from repro.analysis.sanitizers import guard_transfers
 from repro.checkpoint.io import load_adapter_state
@@ -52,36 +53,6 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
 from repro.models.transformer import (merge_paged_cache, paged_prefill_view,
                                       reset_paged_blocks)
-
-# Host->device dispatch meter: every jitted call the generation helpers make
-# increments this (serve_bench reports it; a compiled generate is exactly 1).
-host_dispatches = 0
-
-
-def reset_dispatch_meter() -> None:
-    global host_dispatches
-    host_dispatches = 0
-
-
-def _count_dispatch(n: int = 1) -> None:
-    global host_dispatches
-    host_dispatches += n
-
-
-# requests evicted at a chunk boundary for exceeding their deadline_steps
-# (graceful degradation under load; truncated, not failed)
-timeouts = 0
-
-
-def reset_timeout_meter() -> None:
-    global timeouts
-    timeouts = 0
-
-
-def _count_timeout(n: int = 1) -> None:
-    global timeouts
-    timeouts += n
-
 
 def _model_jit(model, name: str, builder):
     """Per-model jit cache stored ON the model object itself.
@@ -226,7 +197,7 @@ def generate(model, params, prompt, steps: int, max_len: int, adapters=None,
     if key is None:
         key = jax.random.key(0)
     run = _compiled_generate(model)
-    _count_dispatch()
+    trace.count("serve.dispatches")
     return run(params, prompt, adapters, key, steps=int(steps),
                max_len=int(max_len), temperature=float(temperature))
 
@@ -265,7 +236,7 @@ def generate_hostloop(model, params, prompt, steps: int, max_len: int,
     tok = prompt[:, :1]
     out = [tok]
     for t in range(p + steps - 1):
-        _count_dispatch()
+        trace.count("serve.dispatches")
         logits, cache = step(params, cache, tok, jnp.full((b,), t),
                              adapters)
         nxt = (prompt[:, t + 1:t + 2] if t + 1 < p
@@ -288,7 +259,7 @@ def generate_banked_hostloop(model, params, bank: AdapterBank, adapter_ids,
     tok = prompt[:, :1]
     out = [tok]
     for t in range(p + steps - 1):
-        _count_dispatch()
+        trace.count("serve.dispatches")
         logits, cache = step(params, cache, tok, jnp.full((b,), t), bank, ids)
         nxt = (prompt[:, t + 1:t + 2] if t + 1 < p
                else jnp.argmax(logits[:, -1:, :vocab],
@@ -386,8 +357,8 @@ class Request:
     ``deadline_steps`` caps how many tokens the scheduler will spend on
     this request before evicting it at the next chunk boundary (graceful
     degradation under load): a request that hits the cap finishes with
-    its tokens truncated, ``timed_out`` set, and the module ``timeouts``
-    counter bumped — its slot and blocks recycle immediately."""
+    its tokens truncated, ``timed_out`` set, and the ``serve.timeouts``
+    trace counter bumped — its slot and blocks recycle immediately."""
     rid: int
     prompt: np.ndarray
     steps: int
@@ -489,7 +460,17 @@ def serve_scheduled(model, params, requests, *, bank=None, max_batch=4,
     additionally runs both engines under
     ``jax.transfer_guard("disallow")``; enable it on warmed shapes with
     device-resident params (tracing/compiling under the guard would trip
-    on legitimate staging transfers)."""
+    on legitimate staging transfers).
+
+    Under :func:`repro.analysis.trace.tracing` every loop iteration is a
+    ``serve.boundary`` span holding ``serve.admit`` per group (children
+    ``.stage``, ``.call``, ``.sync``), ``serve.chunk`` (children
+    ``.stage``, ``.call``, ``.sync``, ``.evict``) or ``serve.wait``; a
+    request's time from its arrival to its group's admission is a
+    ``serve.queued`` span; the counters are ``serve.dispatches``,
+    ``serve.admitted``, ``serve.timeouts``, ``serve.decode_tokens`` (tokens
+    kept from chunks) and ``serve.decode_slot_steps`` (slots x steps
+    run)."""
     reqs = sorted(requests, key=lambda r: (r.arrival, r.rid))
     if not reqs:
         return []
@@ -553,47 +534,45 @@ def serve_scheduled(model, params, requests, *, bank=None, max_batch=4,
         # promotion and slot pinning on the observed ids)
         ids_arr[r.slot] = 0
 
-    boundary = 0
-    while pending or running:
-        if on_boundary is not None:
-            # the swap window: between decode chunks / admission groups
-            on_boundary(boundary)
-        boundary += 1
-        now = clock()
-        # ---- admission: FIFO same-length groups into free slots.  The
-        # head of the queue is never overtaken (a shorter-prompt request
-        # behind it cannot jump ahead), which keeps the loop deterministic
-        # and starvation-free.
-        while pending and free_slots and pending[0].arrival <= now:
-            plen = len(pending[0].prompt)
-            group = []
-            for r in pending:
-                if (r.arrival <= now and len(r.prompt) == plen
-                        and len(group) < len(free_slots)
-                        and pool.available >= mb * (len(group) + 1)):
-                    group.append(r)
-                else:
-                    break
-            slot_map = None
-            if group and live is not None:
-                for r in group:
-                    if not live.has(r.adapter_id):
-                        raise ValueError(
-                            f"request rid={r.rid}: unknown tenant "
-                            f"{r.adapter_id} (store holds {live.tenants})")
-                # hot slots gathered by running requests are pinned; shrink
-                # the group from the tail (head keeps FIFO priority) until
-                # its distinct tenants fit the unpinned hot set, deferring
-                # admission entirely when even the head cannot be promoted
-                pinned = {int(ids_arr[r.slot]) for r in running}
-                while group:
-                    slot_map = live.acquire(
-                        [r.adapter_id for r in group], pinned)
-                    if slot_map is not None:
-                        break
-                    group.pop()
-            if not group:
+    def next_group(now):
+        """The next admission group and, for a live bank, its tenants' hot
+        slots: FIFO same-length requests that have arrived, up to the free
+        slots and blocks.  The head of the queue is never overtaken (a
+        shorter-prompt request behind it cannot jump ahead), which keeps
+        the loop deterministic and starvation-free."""
+        plen = len(pending[0].prompt)
+        group = []
+        for r in pending:
+            if (r.arrival <= now and len(r.prompt) == plen
+                    and len(group) < len(free_slots)
+                    and pool.available >= mb * (len(group) + 1)):
+                group.append(r)
+            else:
                 break
+        slot_map = None
+        if group and live is not None:
+            for r in group:
+                if not live.has(r.adapter_id):
+                    raise ValueError(
+                        f"request rid={r.rid}: unknown tenant "
+                        f"{r.adapter_id} (store holds {live.tenants})")
+            # hot slots gathered by running requests are pinned; shrink the
+            # group from the tail (head keeps FIFO priority) until its
+            # distinct tenants fit the unpinned hot set, deferring admission
+            # entirely when even the head cannot be promoted
+            pinned = {int(ids_arr[r.slot]) for r in running}
+            while group:
+                slot_map = live.acquire([r.adapter_id for r in group],
+                                        pinned)
+                if slot_map is not None:
+                    break
+                group.pop()
+        return group, slot_map
+
+    def admit_group(group, slot_map):
+        nonlocal cache, table, tok, pos, active
+        plen = len(group[0].prompt)
+        with trace.span("serve.admit.stage"):
             for r in group:
                 pending.remove(r)
             slots = [free_slots.pop(0) for _ in group]
@@ -611,40 +590,50 @@ def serve_scheduled(model, params, requests, *, bank=None, max_batch=4,
                                   jnp.int32)
             adapters = (cur_bank().requests(jnp.asarray(gather_ids))
                         if bank is not None else None)
-            _count_dispatch()
+        with trace.span("serve.admit.call"):
+            trace.count("serve.dispatches")
             cache, first = admit(params, cache, prompts, jnp.asarray(rows),
                                  sl, jnp.asarray(rows.reshape(-1)), adapters)
             tok = tok.at[sl, 0].set(first)
             pos = pos.at[sl].set(plen)
             active = active.at[sl].set(True)
-            tnow = clock()
+        with trace.span("serve.admit.sync"):
             first_host = np.asarray(first)
-            for i, r in enumerate(group):
-                r.tokens = [int(first_host[i])]
-                r.t_first = None if tnow == float("inf") else tnow
-                running.append(r)
-            for r in [r for r in group if r.steps <= 1]:
-                finish(r, r.t_first)
-            for r in [r for r in group
-                      if r in running and r.deadline_steps is not None
-                      and len(r.tokens) >= r.deadline_steps]:
-                r.timed_out = True
-                _count_timeout()
-                finish(r, r.t_first)
+        # the first token is on the host from here
+        tnow = clock()
+        trace.count("serve.admitted", len(group))
+        for i, r in enumerate(group):
+            r.tokens = [int(first_host[i])]
+            r.t_first = None if tnow == float("inf") else tnow
+            running.append(r)
+        for r in [r for r in group if r.steps <= 1]:
+            finish(r, r.t_first)
+        for r in [r for r in group
+                  if r in running and r.deadline_steps is not None
+                  and len(r.tokens) >= r.deadline_steps]:
+            r.timed_out = True
+            trace.count("serve.timeouts")
+            finish(r, r.t_first)
 
-        # ---- decode chunk + eviction
-        if running:
+    def decode_chunk():
+        nonlocal cache, tok, pos
+        with trace.span("serve.chunk.stage"):
             if live is not None:
                 # recency driven by the ids flowing through the scheduler
                 live.touch([r.adapter_id for r in running])
             adapters = (cur_bank().requests(jnp.asarray(ids_arr))
                         if bank is not None else None)
-            _count_dispatch()
+        with trace.span("serve.chunk.call"):
+            trace.count("serve.dispatches")
             cache, tok, pos, toks = chunk_run(params, cache, tok, pos,
                                               active, table, adapters,
                                               steps=chunk)
+        trace.count("serve.decode_slot_steps", max_batch * chunk)
+        with trace.span("serve.chunk.sync"):
             toks = np.asarray(toks)
-            tnow = clock()
+        tnow = clock()
+        kept = 0
+        with trace.span("serve.chunk.evict"):
             for r in list(running):
                 # a deadline caps how many tokens this request may consume;
                 # the prefix generated up to the cap is identical to an
@@ -654,16 +643,46 @@ def serve_scheduled(model, params, requests, *, bank=None, max_batch=4,
                        else min(r.steps, r.deadline_steps))
                 take = max(0, min(chunk, cap - len(r.tokens)))
                 r.tokens.extend(int(t) for t in toks[r.slot, :take])
+                kept += take
                 if len(r.tokens) >= r.steps:
                     finish(r, None if tnow == float("inf") else tnow)
                 elif len(r.tokens) >= cap:
                     r.timed_out = True
-                    _count_timeout()
+                    trace.count("serve.timeouts")
                     finish(r, None if tnow == float("inf") else tnow)
-        elif pending:
-            gap = pending[0].arrival - clock()
-            if gap > 0:
-                time.sleep(min(gap, 0.02))
+        trace.count("serve.decode_tokens", kept)
+
+    boundary = 0
+    while pending or running:
+        with trace.span("serve.boundary"):
+            if on_boundary is not None:
+                # the swap window: between decode chunks / admission groups
+                on_boundary(boundary)
+            boundary += 1
+            now = clock()
+            while pending and free_slots and pending[0].arrival <= now:
+                group, slot_map = next_group(now)
+                if not group:
+                    break
+                if trace.enabled():
+                    # each request waited from its arrival (the loop's
+                    # start when arrivals are not honored) until now
+                    for r in group:
+                        trace.since("serve.queued",
+                                    t0 + (r.arrival if wait else 0.0),
+                                    rid=r.rid)
+                with trace.span("serve.admit", rids=[r.rid for r in group],
+                                size=len(group),
+                                prompt_len=len(group[0].prompt)):
+                    admit_group(group, slot_map)
+            if running:
+                with trace.span("serve.chunk"):
+                    decode_chunk()
+            elif pending:
+                gap = pending[0].arrival - clock()
+                if gap > 0:
+                    with trace.span("serve.wait"):
+                        time.sleep(min(gap, 0.02))
     return sorted(reqs, key=lambda r: r.rid)
 
 
@@ -792,7 +811,15 @@ def main(argv=None):
                          "this many device-resident slots; the remaining "
                          "tenants overflow to host RAM and are LRU-promoted "
                          "on demand (0 = whole bank on device, no overflow)")
+    ap.add_argument("--trace-out", default=None, metavar="PATH",
+                    help="trace the job's spans and counters and write them "
+                         "to PATH as Chrome trace-event JSON (Perfetto)")
     args = ap.parse_args(argv)
+    with trace.written_to(args.trace_out):
+        return _serve(args)
+
+
+def _serve(args):
     enable_compile_cache()
 
     cfg = get_config(args.arch)
@@ -813,7 +840,6 @@ def main(argv=None):
                              steps=args.steps, tenants=bank.size,
                              vocab=cfg.vocab_size,
                              deadline_steps=args.deadline_steps)
-        reset_timeout_meter()
         serve_bank = bank
         if args.hot_slots:
             serve_bank = LiveAdapterBank.from_bank(bank,

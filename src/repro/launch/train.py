@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import argparse
 
+from repro.analysis import trace
 from repro.configs import ARCHS, get_config
 from repro.configs.base import FederatedConfig, LoRAConfig, OptimizerConfig
 from repro.core.aggregation import STRATEGIES
@@ -111,7 +112,15 @@ def main(argv=None):
     ap.add_argument("--resume", default=None,
                     help="checkpoint to restore (incl. PRNG key + round, so "
                          "the run continues bit-exactly)")
+    ap.add_argument("--trace-out", default=None, metavar="PATH",
+                    help="trace the job's spans and counters and write them "
+                         "to PATH as Chrome trace-event JSON (Perfetto)")
     args = ap.parse_args(argv)
+    with trace.written_to(args.trace_out):
+        return _train(args)
+
+
+def _train(args):
     enable_compile_cache()
 
     cfg = get_config(args.arch)

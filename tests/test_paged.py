@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis import trace
 from repro.configs.base import LoRAConfig, ModelConfig
 from repro.core.lora import AdapterBank, init_adapter_set
 from repro.kernels import dispatch
@@ -397,9 +398,9 @@ def test_scheduled_block_starvation_waits_not_fails():
 def test_deadline_evicts_at_chunk_boundary_with_exact_prefix():
     """Graceful degradation: a request with deadline_steps=8 inside a
     steps=32 ask is evicted at a chunk boundary with EXACTLY 8 tokens,
-    marked timed_out, counted by the timeout meter — and its tokens are a
-    bit-exact prefix of the un-deadlined run (eviction only ever happens
-    between chunks, so it cannot perturb decode numerics)."""
+    marked timed_out, counted by the serve.timeouts counter — and its
+    tokens are a bit-exact prefix of the un-deadlined run (eviction only
+    ever happens between chunks, so it cannot perturb decode numerics)."""
     cfg = _cfg()
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
@@ -409,15 +410,15 @@ def test_deadline_evicts_at_chunk_boundary_with_exact_prefix():
         model, params,
         [serve.Request(rid=0, prompt=prompt[0], steps=32)],
         max_batch=2, block_size=4, chunk=4, max_len=40, wait=False)
-    serve.reset_timeout_meter()
-    done = serve.serve_scheduled(
-        model, params,
-        [serve.Request(rid=0, prompt=prompt[0], steps=32,
-                       deadline_steps=8)],
-        max_batch=2, block_size=4, chunk=4, max_len=40, wait=False)
+    with trace.tracing() as t:
+        done = serve.serve_scheduled(
+            model, params,
+            [serve.Request(rid=0, prompt=prompt[0], steps=32,
+                           deadline_steps=8)],
+            max_batch=2, block_size=4, chunk=4, max_len=40, wait=False)
     (r,) = done
     assert r.timed_out and len(r.tokens) == 8
-    assert serve.timeouts == 1
+    assert t.counters["serve.timeouts"] == 1
     np.testing.assert_array_equal(np.asarray(r.tokens),
                                   np.asarray(full[0].tokens)[:8])
     # an un-deadlined sibling is untouched
@@ -433,21 +434,21 @@ def test_deadline_frees_slot_for_queued_request():
     params = model.init(jax.random.key(0))
     prompt = np.asarray(jax.random.randint(jax.random.key(6), (3, 4), 0,
                                            cfg.vocab_size), np.int32)
-    serve.reset_timeout_meter()
     reqs = [serve.Request(rid=0, prompt=prompt[0], steps=24,
                           deadline_steps=4),
             serve.Request(rid=1, prompt=prompt[1], steps=24,
                           deadline_steps=4),
             serve.Request(rid=2, prompt=prompt[2], steps=6)]
-    done = serve.serve_scheduled(model, params, reqs, max_batch=2,
-                                 block_size=4, chunk=4, max_len=32,
-                                 wait=False)
+    with trace.tracing() as t:
+        done = serve.serve_scheduled(model, params, reqs, max_batch=2,
+                                     block_size=4, chunk=4, max_len=32,
+                                     wait=False)
     by_rid = {r.rid: r for r in done}
     assert len(by_rid) == 3
     assert by_rid[0].timed_out and len(by_rid[0].tokens) == 4
     assert by_rid[1].timed_out and len(by_rid[1].tokens) == 4
     assert not by_rid[2].timed_out and len(by_rid[2].tokens) == 6
-    assert serve.timeouts == 2
+    assert t.counters["serve.timeouts"] == 2
 
 
 def test_deadline_not_hit_is_a_noop():
@@ -458,13 +459,14 @@ def test_deadline_not_hit_is_a_noop():
     params = model.init(jax.random.key(0))
     prompt = np.asarray(jax.random.randint(jax.random.key(7), (1, 5), 0,
                                            cfg.vocab_size), np.int32)
-    serve.reset_timeout_meter()
-    runs = [serve.serve_scheduled(
-        model, params,
-        [serve.Request(rid=0, prompt=prompt[0], steps=6, deadline_steps=d)],
-        max_batch=1, block_size=4, chunk=3, max_len=16, wait=False)
-        for d in (None, 32)]
-    assert serve.timeouts == 0
+    with trace.tracing() as t:
+        runs = [serve.serve_scheduled(
+            model, params,
+            [serve.Request(rid=0, prompt=prompt[0], steps=6,
+                           deadline_steps=d)],
+            max_batch=1, block_size=4, chunk=3, max_len=16, wait=False)
+            for d in (None, 32)]
+    assert "serve.timeouts" not in t.counters
     for run in runs:
         assert not run[0].timed_out and len(run[0].tokens) == 6
     np.testing.assert_array_equal(runs[0][0].tokens, runs[1][0].tokens)

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis import trace
 from repro.configs.base import LoRAConfig, ModelConfig
 from repro.core.lora import AdapterBank, init_adapter_set
 from repro.kernels import dispatch
@@ -152,11 +153,11 @@ def test_compiled_generate_bit_identical_to_hostloop(served, variant):
         host = lambda: serve.generate_banked_hostloop(model, params, bank,
                                                       ids, prompt, steps,
                                                       max_len)
-    serve.reset_dispatch_meter()
-    got = comp()
-    assert serve.host_dispatches == 1
-    want = host()
-    assert serve.host_dispatches == 1 + prompt.shape[1] + steps - 1
+    with trace.tracing() as t:
+        got = comp()
+        assert t.counters["serve.dispatches"] == 1
+        want = host()
+    assert t.counters["serve.dispatches"] == 1 + prompt.shape[1] + steps - 1
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
