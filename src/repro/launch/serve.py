@@ -46,8 +46,9 @@ from repro.configs import ARCHS, get_config
 from repro.configs.base import LoRAConfig
 from repro.core.lora import (AdapterBank, AdapterSet, LiveAdapterBank,
                              init_adapter_set)
-from repro.core.quant import (apply_quant_flag, dequantize_tree,
-                              has_quantized, requantize_merged)
+from repro.core.quant import (QuantizedLinear, apply_quant_flag,
+                              dequantize_tree, has_quantized,
+                              requantize_merged)
 from repro.kernels import dispatch
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
@@ -108,6 +109,58 @@ def _prepare_base(m, params):
         if dispatch.resolve_mode() == "reference":
             return dequantize_tree(params)
     return params
+
+
+# matmul precisions at which XLA's dot on a TPU rounds float32 operands to
+# bfloat16 in one pass (None is the default)
+_ONE_BF16_PASS = (None, "default", "fastest", "bfloat16")
+
+_to_bf16 = jax.jit(lambda ws: [w.astype(jnp.bfloat16) for w in ws])
+
+
+def _dots_round_to_bf16(m) -> bool:
+    """Whether every dot the engines run on the frozen base already rounds
+    a float32 weight to bfloat16: XLA's dots (the reference kernel tier; a
+    Pallas kernel makes its own) on a TPU at the default matmul precision."""
+    if jax.default_backend() != "tpu":
+        return False
+    if jax.config.jax_default_matmul_precision not in _ONE_BF16_PASS:
+        return False
+    with dispatch.scope(m.cfg.use_pallas):
+        return dispatch.resolve_mode() == "reference"
+
+
+def serving_base(m, params):
+    """The frozen base as one serving run reads it, made once per run.
+
+    Where every dot already rounds a float32 weight to bfloat16
+    (:func:`_dots_round_to_bf16`), the float32 leaves the model reads only
+    as dot operands (``Model.dot_only``: the projections and an untied head)
+    are cast to bfloat16 in one jitted call.  Each dot then gets the operand
+    it made itself before, so the tokens are the same, while no engine call
+    converts a weight again and no step reads one at 4 bytes.  The
+    embedding, norms, packed (QuantizedLinear) and narrower leaves pass
+    through; elsewhere ``params`` comes back as it is.  Recorded as the
+    span ``serve.prepare`` (``leaves``, ``bytes``) and the counter
+    ``serve.base_bf16_leaves``."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        params, is_leaf=lambda x: isinstance(x, QuantizedLinear))
+    picked = []
+    if _dots_round_to_bf16(m):
+        picked = [i for i, (path, leaf) in enumerate(flat)
+                  if not isinstance(leaf, QuantizedLinear)
+                  and leaf.dtype == jnp.float32
+                  and m.dot_only(tuple(getattr(k, "key", k) for k in path))]
+    nbytes = sum(flat[i][1].size * 2 for i in picked)
+    trace.count("serve.base_bf16_leaves", len(picked))
+    if not picked:
+        return params
+    with trace.span("serve.prepare", leaves=len(picked), bytes=nbytes):
+        leaves = [leaf for _, leaf in flat]
+        cast = jax.block_until_ready(_to_bf16([leaves[i] for i in picked]))
+        for i, w in zip(picked, cast):
+            leaves[i] = w
+        return treedef.unflatten(leaves)
 
 
 def _prepare_adapters(m, adapters):
@@ -470,7 +523,9 @@ def serve_scheduled(model, params, requests, *, bank=None, max_batch=4,
     ``serve.queued`` span; the counters are ``serve.dispatches``,
     ``serve.admitted``, ``serve.timeouts``, ``serve.decode_tokens`` (tokens
     kept from chunks) and ``serve.decode_slot_steps`` (slots x steps
-    run)."""
+    run).  Before the loop, :func:`serving_base` makes the frozen base's
+    view for the run (span ``serve.prepare``, counter
+    ``serve.base_bf16_leaves``)."""
     reqs = sorted(requests, key=lambda r: (r.arrival, r.rid))
     if not reqs:
         return []
@@ -504,6 +559,7 @@ def serve_scheduled(model, params, requests, *, bank=None, max_batch=4,
     active = jnp.zeros((max_batch,), bool)
     ids_arr = np.zeros((max_batch,), np.int32)
     free_slots = list(range(max_batch))
+    params = serving_base(model, params)
     admit = _jit_paged_admit(model)
     chunk_run = _jit_paged_chunk(model)
     if guard is not None:

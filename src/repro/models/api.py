@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.lora import as_adapter_set
+from repro.core.quant import ELIGIBLE
 from repro.kernels import dispatch
 from repro.models.layers import norm_params, apply_norm
 from repro.models.transformer import (apply_stack, banked_scan_layout,
@@ -52,6 +53,16 @@ class Model:
                 kp, (PATCH_EMBED_DIM, cfg.d_model)) *
                 PATCH_EMBED_DIM ** -0.5).astype(pdt)
         return params
+
+    def dot_only(self, path) -> bool:
+        """Whether the base leaf at ``path`` (its dict keys from the root)
+        is read only as a matrix-product operand: the dense projections that
+        route through ``linear`` (``core/quant.ELIGIBLE``) and an untied
+        ``lm_head``.  The embedding is gathered (and is the head when tied),
+        so it is not; nor are norms, gates, routers or biases."""
+        if path == ("lm_head",):
+            return not self.cfg.tie_embeddings
+        return len(path) >= 2 and path[-1] in ELIGIBLE.get(path[-2], ())
 
     # ------------------------------------------------------------- forward
     def _embed(self, params, batch):
