@@ -1,22 +1,75 @@
-"""Plain reference of the configurations' transformer, in straightforward
-``jax.numpy``: embedding, pre-norm decoder layers (causal grouped-query
-attention with rotary positions and optional q/k RMS norm, SwiGLU MLP) and
-the head, with LoRA on the projections a job adapts.  No kernel, cache or
-batching of the system under test; it imports nothing of the program.
+"""The dense family: a decoder of identical pre-norm layers (causal
+grouped-query attention with rotary positions and optional q/k RMS norm, a
+SwiGLU MLP), an embedding and an untied head, with LoRA on the projections a
+job adapts.  The interface each family provides is in ``__init__.py``.
 
-It reads the weights the benchmark made (``model.make_params``), laid out as
-the program takes them.  In float32 it computes at the precision the
-configuration states: where ``precision.matmul_operand_bytes`` is 2, every
-matrix product rounds its operands to bfloat16 and sums in float32 (one pass
-of the TPU's matrix unit), and every other op is float32.  With the weights
-cast to bfloat16 it computes everything in bfloat16 — the control that the
-comparison must fail.  Callers run it under ``default_matmul_precision
-("highest")``, so nothing is rounded that this module does not round.
+The reference below is straightforward ``jax.numpy``: no kernel, cache or
+batching of the system under test, and nothing of the program imported.
+Where ``precision.matmul_operand_bytes`` is 2 a matrix product takes its
+float32 operands as bfloat16 and sums in float32 (one pass of the TPU's
+matrix unit).  The work counts are ``flops.py``'s, which count this layer.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
+
+from flops import decode_step_work, train_step_flops  # noqa: F401
+
+# configuration-file key -> the program's ModelConfig field
+_FIELDS = {"hidden_size": "d_model", "intermediate_size": "d_ff",
+           "num_hidden_layers": "num_layers",
+           "num_attention_heads": "num_heads",
+           "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
+           "vocab_size": "vocab_size", "rope_theta": "rope_theta",
+           "norm": "norm", "tie_word_embeddings": "tie_embeddings"}
+
+
+def program_config(cfg: dict):
+    """The program's ModelConfig for a configuration file: the registry
+    entry ``cfg["arch"]`` with the file's sizes.  A size that differs from
+    the registry's must be listed in the file's ``reduced``, or the run
+    stops: the benchmark would otherwise measure another model than the one
+    it names."""
+    from repro.configs import get_config
+    registry = get_config(cfg["arch"])
+    changed = [k for k, f in _FIELDS.items()
+               if getattr(registry, f) != cfg[k]]
+    if set(changed) - set(cfg["reduced"]):
+        raise ValueError(f"{cfg['name']}: {sorted(set(changed))} differ from "
+                         f"the program's {cfg['arch']} but are not in "
+                         f"'reduced' {cfg['reduced']}")
+    mc = dataclasses.replace(
+        registry, param_dtype=cfg["torch_dtype"], dtype=cfg["torch_dtype"],
+        **{f: cfg[k] for k, f in _FIELDS.items()})
+    if mc.qk_norm != bool(cfg.get("qk_norm", False)):
+        raise ValueError(f"{cfg['name']}: qk_norm differs from the program")
+    if mc.mlp_variant != "swiglu" or cfg["hidden_act"] != "silu":
+        raise ValueError(f"{cfg['name']}: the reference computes a SwiGLU MLP")
+    if mc.parallel_residual or mc.attn_window or mc.attn_logit_softcap:
+        raise ValueError(
+            f"{cfg['name']}: the dense family has no parallel residual, "
+            "window or logit soft-cap; a configuration with them names a "
+            "family that computes them (\"family\": \"<name>\", "
+            "bench/families/<name>.py)")
+    return mc
+
+
+def lora_shapes(cfg: dict, rank: int, targets, lead=()):
+    """{target: {"a": lead + (L, r, d_in), "b": lead + (L, d_out, r)}}."""
+    d, L = cfg["hidden_size"], cfg["num_hidden_layers"]
+    hd = cfg["head_dim"]
+    out = {"q": cfg["num_attention_heads"] * hd,
+           "v": cfg["num_key_value_heads"] * hd}
+    return {t: {"a": lead + (L, rank, d), "b": lead + (L, out[t], rank)}
+            for t in targets}
+
+
+def program_lora(lora: dict) -> dict:
+    """The same adapters in the program's tree layout."""
+    return {"stack": {"repeat": {"p0": {"attn": lora}}}}
 
 
 def _norm(cfg, x, scale, bias=None):

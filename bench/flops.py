@@ -1,4 +1,6 @@
-"""Model FLOPs and required bytes, from a configuration file's own sizes.
+"""Model FLOPs and required bytes of the dense family's layer
+(``families/dense.py`` takes its two work counts from here), and the least
+time of any family's work at the chip's peaks (``least_time_s``).
 
 Nothing here calls the system under test: the counts come from the widths
 in ``bench/configs/<config>.json`` alone, so a later change to the program

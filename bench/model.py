@@ -1,13 +1,18 @@
 """A configuration file turned into what the system under test runs, and the
 weights and adapters the benchmark makes for it from ``--seed``.
 
-The benchmark makes every weight itself, on the device, in one jitted call:
-the program's own ``init`` is used only through ``jax.eval_shape`` to learn
-the layout its functions take.  The reference (``reference.py``) reads the
-same arrays; nothing it compares against was made by the program.
+A configuration's family (``bench/families/``) holds what the benchmark
+knows of its architecture: the program's config, the adapter layout, the
+reference and the work counts.  The benchmark makes every weight itself, on
+the device, in one jitted call: the program's own ``init`` is used only
+through ``jax.eval_shape`` to learn the layout its functions take.  The
+family's reference reads the same arrays; nothing it compares against was
+made by the program.
 """
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 import os
 
@@ -15,48 +20,54 @@ import jax
 import jax.numpy as jnp
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-
-# configuration-file key -> the program's ModelConfig field
-_FIELDS = {"hidden_size": "d_model", "intermediate_size": "d_ff",
-           "num_hidden_layers": "num_layers",
-           "num_attention_heads": "num_heads",
-           "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
-           "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-           "norm": "norm", "tie_word_embeddings": "tie_embeddings"}
+# directories holding family modules; a name in two is the first one's
+FAMILY_DIRS = [os.path.join(HERE, "families")]
+# what every family module provides (bench/families/__init__.py)
+INTERFACE = ("program_config", "lora_shapes", "program_lora", "forward",
+             "loss", "cast", "train_step_flops", "decode_step_work")
 
 
 def load_config(name: str, directory: str | None = None) -> dict:
+    """A configuration file; its family is loaded and checked here, so a
+    file naming an unknown family stops before any device work."""
     with open(os.path.join(directory or os.path.join(HERE, "configs"),
                            name + ".json")) as f:
-        return json.load(f)
+        cfg = json.load(f)
+    family(cfg)
+    return cfg
 
 
-def program_config(cfg: dict):
-    """The program's ModelConfig for a configuration file: the registry
-    entry ``cfg["arch"]`` with the file's sizes.  A size that differs from
-    the registry's must be listed in the file's ``reduced``, or the run
-    stops: the benchmark would otherwise measure another model than the one
-    it names."""
-    import dataclasses
-    from repro.configs import get_config
-    registry = get_config(cfg["arch"])
-    changed = [k for k, f in _FIELDS.items()
-               if getattr(registry, f) != cfg[k]]
-    if set(changed) - set(cfg["reduced"]):
-        raise ValueError(f"{cfg['name']}: {sorted(set(changed))} differ from "
-                         f"the program's {cfg['arch']} but are not in "
-                         f"'reduced' {cfg['reduced']}")
-    mc = dataclasses.replace(
-        registry, param_dtype=cfg["torch_dtype"], dtype=cfg["torch_dtype"],
-        **{f: cfg[k] for k, f in _FIELDS.items()})
-    if mc.qk_norm != bool(cfg.get("qk_norm", False)):
-        raise ValueError(f"{cfg['name']}: qk_norm differs from the program")
-    if mc.mlp_variant != "swiglu" or cfg["hidden_act"] != "silu":
-        raise ValueError(f"{cfg['name']}: the reference computes a SwiGLU MLP")
-    if mc.parallel_residual or mc.attn_window or mc.attn_logit_softcap:
-        raise ValueError(f"{cfg['name']}: the reference has no parallel "
-                         "residual, window or logit soft-cap")
-    return mc
+def family(cfg: dict):
+    """The family module a configuration names (``dense`` where it names
+    none)."""
+    return load_family(cfg.get("family", "dense"))
+
+
+def load_family(name: str):
+    """The family module ``<name>.py``, loaded by path once per process and
+    checked for the whole interface."""
+    found = {}
+    for d in reversed(FAMILY_DIRS):
+        found.update({f[:-3]: os.path.join(d, f) for f in os.listdir(d)
+                      if f.endswith(".py") and f != "__init__.py"})
+    if name not in found:
+        raise ValueError(f"unknown family {name!r}; known: {sorted(found)}")
+    return _load(found[name])
+
+
+@functools.cache
+def _load(path: str):
+    name = os.path.basename(path)[:-3]
+    spec = importlib.util.spec_from_file_location(
+        "bench_family_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [f for f in INTERFACE if not callable(getattr(mod, f, None))]
+    if missing:
+        raise ValueError(f"family {name!r} ({path}) lacks {missing}")
+    if not hasattr(mod, "make_params"):
+        mod.make_params = make_params
+    return mod
 
 
 def seed_key(seed: int):
@@ -92,22 +103,13 @@ def make_params(model, key):
     return init(key)
 
 
-def lora_shapes(cfg: dict, rank: int, targets, lead=()):
-    """{target: {"a": lead + (L, r, d_in), "b": lead + (L, d_out, r)}}."""
-    d, L = cfg["hidden_size"], cfg["num_hidden_layers"]
-    hd = cfg["head_dim"]
-    out = {"q": cfg["num_attention_heads"] * hd,
-           "v": cfg["num_key_value_heads"] * hd}
-    return {t: {"a": lead + (L, rank, d), "b": lead + (L, out[t], rank)}
-            for t in targets}
-
-
 def make_lora(cfg: dict, key, *, rank: int, targets, a_std: float,
               b_std: float, lead=(), shared_lead: bool = False):
     """Adapter weights {target: {"a", "b"}} with leading dims ``lead``.
     ``shared_lead``: every index of the leading dims gets the same values
     (a federated job whose clients start from one adapter)."""
-    shapes = lora_shapes(cfg, rank, targets, () if shared_lead else lead)
+    shapes = family(cfg).lora_shapes(cfg, rank, targets,
+                                      () if shared_lead else lead)
 
     @jax.jit
     def init(key):
@@ -123,8 +125,3 @@ def make_lora(cfg: dict, key, *, rank: int, targets, a_std: float,
         return out
 
     return init(key)
-
-
-def program_lora(lora: dict) -> dict:
-    """The same adapters in the program's tree layout."""
-    return {"stack": {"repeat": {"p0": {"attn": lora}}}}

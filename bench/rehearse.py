@@ -43,12 +43,13 @@ def train_programs(cfg, mix, sharded):
     from repro.models.api import build_model
     import model as bmodel
     import traffic
-    model = build_model(bmodel.program_config(cfg))
+    fam = bmodel.family(cfg)
+    model = build_model(fam.program_config(cfg))
     n = mix["clients"]
     params = sharded(jax.eval_shape(model.init, jax.random.key(0)))
-    lora = sharded(bmodel.program_lora(jax.tree.map(
+    lora = sharded(fam.program_lora(jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s, jnp.float32),
-        bmodel.lora_shapes(cfg, mix["rank"], mix["targets"], lead=(n,)),
+        fam.lora_shapes(cfg, mix["rank"], mix["targets"], lead=(n,)),
         is_leaf=lambda x: isinstance(x, tuple))))
     aset = AdapterSet(lora=lora, gamma=traffic.sfedlora_gamma(mix),
                       rank=mix["rank"], alpha=mix["alpha"])
@@ -72,16 +73,17 @@ def serve_programs(cfg, mix, sharded):
     from repro.launch import serve
     from repro.models.api import build_model
     import model as bmodel
-    model = build_model(bmodel.program_config(cfg))
+    fam = bmodel.family(cfg)
+    model = build_model(fam.program_config(cfg))
     b = mix["max_batch"]
     mb = -(-(mix["prompt_len"] + mix["output"]["max"]) // mix["block_size"])
     params = sharded(jax.eval_shape(model.init, jax.random.key(0)))
     cache = sharded(jax.eval_shape(lambda: model.init_paged_cache(
         1 + b * mb, mix["block_size"], b)))
-    lora = sharded(bmodel.program_lora(jax.tree.map(
+    lora = sharded(fam.program_lora(jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s, jnp.float32),
-        bmodel.lora_shapes(cfg, mix["rank"], mix["targets"],
-                           lead=(mix["tenants"],)),
+        fam.lora_shapes(cfg, mix["rank"], mix["targets"],
+                        lead=(mix["tenants"],)),
         is_leaf=lambda x: isinstance(x, tuple))))
     i32 = lambda *s: sharded(jax.ShapeDtypeStruct(s, jnp.int32))
     # what AdapterBank.requests(ids) builds, without its host-side id check

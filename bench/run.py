@@ -88,6 +88,11 @@ def main(argv=None) -> int:
     import harness
     import model as bmodel
     import traffic
+    try:
+        cfg = bmodel.load_config(cell["config"])
+    except ValueError as e:
+        print(f"bench: {cell['config']}: {e}", file=sys.stderr)
+        return 2
     cache = harness.use_compile_cache(ROOT)
     if jax.default_backend() != "tpu":
         print(f"bench: JAX found no TPU (backend {jax.default_backend()!r}); "
@@ -100,7 +105,7 @@ def main(argv=None) -> int:
     mix = traffic.load(cell["traffic"])
     reported = {m["name"] for m in bench["end_to_end"]
                 if args.workload in m.get("workloads", [args.workload])}
-    ctx = {"args": args, "cfg": bmodel.load_config(cell["config"]),
+    ctx = {"args": args, "cfg": cfg,
            "mix": mix, "chips": cell["chips"], "t0": T0,
            "meter": harness.CompileMeter(), "spans": harness.Spans(),
            "limits": harness.load_limits(args.workload),

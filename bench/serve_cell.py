@@ -22,7 +22,6 @@ import numpy as np
 import flops
 import harness
 import model as bmodel
-import reference
 import traffic
 
 
@@ -112,17 +111,17 @@ def logit_gaps(cfg, mix, params, bank, reqs, *, control=False):
     gaps over every sampled position."""
     import jax
     import jax.numpy as jnp
+    fam = bmodel.family(cfg)
     p_len, o_max = mix["prompt_len"], mix["output"]["max"]
     total = p_len + o_max - 1
 
     def gaps(p, lora, seq, served):
-        ref = reference.forward(cfg, p, seq, lora)[0, p_len - 1:]
+        ref = fam.forward(cfg, p, seq, lora)[0, p_len - 1:]
         best = ref.max(-1)
         out = [best - jnp.take_along_axis(ref, served[:, None], -1)[:, 0]]
         if control:
-            low = reference.forward(
-                cfg, reference.cast(p, jnp.bfloat16), seq,
-                reference.cast(lora, jnp.bfloat16))[0, p_len - 1:]
+            low = fam.forward(cfg, fam.cast(p, jnp.bfloat16), seq,
+                              fam.cast(lora, jnp.bfloat16))[0, p_len - 1:]
             tok = jnp.argmax(low, -1)
             out.append(best - jnp.take_along_axis(ref, tok[:, None], -1)[:, 0])
         return out
@@ -150,11 +149,12 @@ def build(cfg: dict, mix: dict, seed: int):
     import jax
     from repro.core.lora import AdapterBank
     from repro.models.api import build_model
-    model = build_model(bmodel.program_config(cfg))
+    fam = bmodel.family(cfg)
+    model = build_model(fam.program_config(cfg))
     key = bmodel.seed_key(seed)
-    params = bmodel.make_params(model, jax.random.fold_in(key, 1))
+    params = fam.make_params(model, jax.random.fold_in(key, 1))
     bank_lora = make_bank(cfg, mix, key)
-    bank = AdapterBank(lora=bmodel.program_lora(bank_lora),
+    bank = AdapterBank(lora=fam.program_lora(bank_lora),
                        ranks=(mix["rank"],) * mix["tenants"])
     return {"model": model, "params": params, "bank_lora": bank_lora,
             "bank": bank}
@@ -193,9 +193,10 @@ def serve_window(state, mix, reqs, spans, *, detail, on_boundary=None):
 
 def chunk_work(cfg, mix, info, pk) -> tuple[float, str]:
     """Least seconds of one decode chunk's work at the chip's peaks."""
+    work = bmodel.family(cfg).decode_step_work
     total, bound = 0.0, "hbm"
     for i in range(info["steps"]):
-        f, b = flops.decode_step_work(
+        f, b = work(
             cfg, positions=[p + i for p in info["positions"]],
             tenants=info["tenants"], rank=mix["rank"],
             targets=mix["targets"])

@@ -14,9 +14,10 @@ def test_train_control_fails_the_limits(tmp_path):
     ctx = cell_ctx("train", tmp_path)
     cfg, mix, seed = ctx["cfg"], ctx["mix"], ctx["args"].seed
     from repro.models.api import build_model
+    fam = bmodel.family(cfg)
     key = bmodel.seed_key(seed)
-    params = bmodel.make_params(build_model(bmodel.program_config(cfg)),
-                                jax.random.fold_in(key, 1))
+    params = fam.make_params(build_model(fam.program_config(cfg)),
+                             jax.random.fold_in(key, 1))
     ref = train_cell.reference_rounds(cfg, mix, params, seed, key)
     low = train_cell.reference_rounds(cfg, mix, params, seed, key,
                                       dtype="bfloat16")

@@ -18,10 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import flops
 import harness
 import model as bmodel
-import reference
 import traffic
 
 
@@ -43,7 +41,7 @@ def round_tokens(mix: dict) -> int:
 
 
 def round_flops(cfg: dict, mix: dict) -> float:
-    return mix["local_steps"] * flops.train_step_flops(
+    return mix["local_steps"] * bmodel.family(cfg).train_step_flops(
         cfg, sequences=mix["clients"] * mix["batch_per_client"],
         seq=mix["seq_len"], rank=mix["rank"], targets=mix["targets"])
 
@@ -61,13 +59,13 @@ def start_lora(cfg: dict, mix: dict, key):
 @jax.jit
 def _norms(lo, l0):
     return jnp.concatenate([
-        jnp.sqrt(jnp.sum(jnp.square(lo[t][m] - l0[t][m]), axis=(0, 2, 3)))
-        for t in sorted(lo) for m in ("a", "b")])
+        jnp.sqrt(jnp.sum(jnp.square(a - b), axis=(0, *range(2, a.ndim))))
+        for a, b in zip(jax.tree.leaves(lo), jax.tree.leaves(l0))])
 
 
 def change_norms(lora, lora0) -> np.ndarray:
-    """Norm of every leaf's change (per target, matrix and layer, over all
-    clients), in a fixed order."""
+    """Norm of every leaf's change (per leaf of the program's adapter tree
+    and per layer, over all clients), in the tree's order."""
     return np.asarray(_norms(lora, lora0), np.float64)
 
 
@@ -77,9 +75,10 @@ def build(cfg: dict, mix: dict, seed: int, spans):
                                     OptimizerConfig)
     from repro.core.federated import FederatedTrainer
     from repro.models.api import build_model
-    model = build_model(bmodel.program_config(cfg))
+    fam = bmodel.family(cfg)
+    model = build_model(fam.program_config(cfg))
     key = bmodel.seed_key(seed)
-    params = bmodel.make_params(model, jax.random.fold_in(key, 1))
+    params = fam.make_params(model, jax.random.fold_in(key, 1))
     n = mix["clients"]
     trainer = FederatedTrainer(
         model, TimedData(traffic.FederatedData(mix, cfg["vocab_size"], seed),
@@ -94,24 +93,21 @@ def build(cfg: dict, mix: dict, seed: int, spans):
                                 dirichlet_alpha=mix["dirichlet_alpha"]),
         opt_cfg=OptimizerConfig(name=mix["optimizer"], lr=mix["lr"]),
         base_params=params, chunk_rounds=mix["chunk_rounds"])
-    trainer.lora = bmodel.program_lora(start_lora(cfg, mix, key))
+    trainer.lora = fam.program_lora(start_lora(cfg, mix, key))
     trainer.opt_state = {"t": jnp.zeros((n,), jnp.int32)}
     return {"model": model, "params": params, "trainer": trainer, "key": key}
-
-
-def program_lora_of(trainer) -> dict:
-    return trainer.lora["stack"]["repeat"]["p0"]["attn"]
 
 
 def first_chunk(cfg, mix, state, spans):
     """Run the first chunk through the window's own call; returns each
     round's loss and the leaf change norms after the chunk."""
     trainer = state["trainer"]
-    lora0 = start_lora(cfg, mix, state["key"])
+    lora0 = bmodel.family(cfg).program_lora(
+        start_lora(cfg, mix, state["key"]))
     with spans.span("bench.run_chunk"):
         trainer.run(mix["chunk_rounds"])
     out = {"loss": [h["loss"] for h in trainer.history],
-           "norms": change_norms(program_lora_of(trainer), lora0)}
+           "norms": change_norms(trainer.lora, lora0)}
     del lora0
     return out
 
@@ -124,15 +120,15 @@ def reference_rounds(cfg, mix, params, seed, key, *, dtype="float32",
     of each step's loss over clients and steps.  ``dtype="bfloat16"`` computes forward
     and backward in bfloat16 (the control); ``skip_half`` leaves the second
     half of the clients out of the round (a planted fault)."""
+    fam = bmodel.family(cfg)
     dt = jnp.dtype(dtype)
     gamma = traffic.sfedlora_gamma(mix)
     lr, n, steps = mix["lr"], mix["clients"], mix["local_steps"]
     data = traffic.FederatedData(mix, cfg["vocab_size"], seed)
     lora0 = start_lora(cfg, mix, key)
-    p = params if dt == jnp.float32 else reference.cast(params, dt)
+    p = params if dt == jnp.float32 else fam.cast(params, dt)
     grad = jax.jit(jax.value_and_grad(
-        lambda lo, p, toks: reference.loss(cfg, p, toks,
-                                           reference.cast(lo, dt), gamma)))
+        lambda lo, p, toks: fam.loss(cfg, p, toks, fam.cast(lo, dt), gamma)))
     update = jax.jit(lambda lo, g: jax.tree.map(lambda x, y: x - lr * y,
                                                 lo, g))
     trained = n // 2 if skip_half else n
@@ -155,7 +151,8 @@ def reference_rounds(cfg, mix, params, seed, key, *, dtype="float32",
                          stacked[t]["a"].shape),
                          "b": stacked[t]["b"]} for t in stacked}
             out["loss"].append(float(np.mean(losses)))
-        out["norms"] = change_norms(state, lora0)
+        out["norms"] = change_norms(fam.program_lora(state),
+                                    fam.program_lora(lora0))
     return out
 
 
