@@ -51,7 +51,9 @@ width of the matrix unit's operands.  ``mfu.train`` and ``mfu.serve_decode``
 divide the least time of that work by the time measured, so a share of the
 roofline cannot pass 100%.
 
-Optional: ``make_params(model, key)``, the base weights in the program's
-layout made on the device in one jitted call from ``key``; without it the
-family gets ``model.make_params``.
+Optional: ``make_params(model, key, mesh=None)``, the base weights in the
+program's layout made on the device in one jitted call from ``key``; on a
+``mesh`` each chip makes only its share, in the program's placement
+(``repro.sharding.rules.params_sharding``), with the same values as
+without one.  Without it the family gets ``model.make_params``.
 """

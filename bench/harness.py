@@ -1,5 +1,6 @@
 """What every cell shares: the compile cache and compile counter, host spans,
-the profiler slice and its reduction to device busy time and a breakdown,
+the check of a cell's chips against its mix's mesh, the profiler slice and
+its reduction to device busy time and a breakdown (of each traced chip),
 the per-layer metric readers, and the result line.
 """
 from __future__ import annotations
@@ -9,6 +10,7 @@ import glob
 import gzip
 import importlib.util
 import json
+import math
 import os
 import sys
 import time
@@ -149,37 +151,82 @@ class Profile:
         return self.t1 is not None
 
 
+# ------------------------------------------------------------------ chips
+
+def mesh_dims(spec: str) -> tuple[int, ...]:
+    """The shape a mix's ``"mesh"`` names: 1 to 3 positive whole numbers
+    joined by "x" (``"1x4"``), the program's ``--mesh`` spelling that
+    ``repro.launch.mesh.mesh_from_spec`` builds.  Read without touching a
+    device."""
+    dims = tuple(int(d) if d.isdigit() else 0 for d in spec.split("x"))
+    if not 1 <= len(dims) <= 3 or min(dims) < 1:
+        raise ValueError(f"mesh {spec!r}: expected 1 to 3 positive whole "
+                         "numbers joined by 'x', such as '1x4'")
+    return dims
+
+
+def check_chips(cell: dict, mix: dict) -> str | None:
+    """Why a cell's chips and its mix's mesh do not go together, or None.
+    A training mix spreads a cell over several chips by its ``"mesh"``, whose
+    size must be the cell's ``chips``; a cell on one chip may leave it out.
+    A serving cell runs on one chip and takes no mesh."""
+    spec = mix.get("mesh")
+    if spec is None:
+        if cell["chips"] > 1:
+            return (f"{cell['chips']} chips, but the mix names no mesh to "
+                    "spread the cell over them")
+        return None
+    if mix["kind"] != "train":
+        return "a mesh is a training mix's key; serving runs on one chip"
+    try:
+        size = math.prod(mesh_dims(spec))
+    except ValueError as e:
+        return str(e)
+    if size != cell["chips"]:
+        return (f"the mix's mesh {spec!r} spans {size} chips, the cell asks "
+                f"for {cell['chips']}")
+    return None
+
+
 # ---------------------------------------------------------------- the trace
 
-def trace_events(directory: str) -> dict:
+def trace_events(directory: str, chips: int = 1) -> dict:
     """The newest ``.xplane.pb`` under ``directory`` as plain lists:
-    ``device``: [(name, start_ns, dur_ns)] of the ops on the first TPU's
-    "XLA Ops" line (its "XLA Modules" line where a profiler version names
-    no ops); ``host``: [(name, start_ns, dur_ns)] of every host event whose
-    name starts with "bench."."""
+    ``devices``: for each of the first ``chips`` TPUs, by plane name, its
+    ops [(name, start_ns, dur_ns)] from its "XLA Ops" line (its "XLA
+    Modules" line where a profiler version names no ops); ``device``: the
+    first of them; ``host``: [(name, start_ns, dur_ns)] of every host event
+    whose name starts with "bench."."""
     from jax.profiler import ProfileData
     files = sorted(glob.glob(os.path.join(directory, "**", "*.xplane.pb"),
                              recursive=True), key=os.path.getmtime)
     if not files:
-        return {"device": [], "host": []}
+        return {"device": [], "devices": [], "host": []}
     data = ProfileData.from_file(files[-1])
-    device, host = [], []
+    devices, host = [], []
     tpu_planes = sorted((p for p in data.planes
                          if p.name.startswith("/device:TPU:")),
                         key=lambda p: p.name)
-    for plane in tpu_planes[:1]:
+    for plane in tpu_planes[:chips]:
         lines = {line.name: line for line in plane.lines}
         name = "XLA Ops" if "XLA Ops" in lines else "XLA Modules"
-        if name in lines:
-            line = lines[name]
-            device += [(e.name, e.start_ns, e.duration_ns)
-                       for e in line.events]
+        devices.append([(e.name, e.start_ns, e.duration_ns)
+                        for e in lines[name].events] if name in lines else [])
     for plane in data.planes:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
                 host += [(e.name, e.start_ns, e.duration_ns)
                          for e in line.events if e.name.startswith("bench.")]
-    return {"device": device, "host": host}
+    return {"device": devices[0] if devices else [], "devices": devices,
+            "host": host}
+
+
+def per_device(events: dict) -> list[dict]:
+    """``events`` once for each traced device, with that device's ops as
+    ``device``: what ``busy_ns``, ``idle_gaps`` and ``breakdown`` read.  A
+    trace recorded with one device's ops alone gives that one."""
+    return [{**events, "device": ops}
+            for ops in events.get("devices") or [events["device"]]]
 
 
 def save_events(events: dict, path: str):
@@ -216,6 +263,12 @@ def busy_ns(events: dict, lo: float, hi: float) -> float:
     """Nanoseconds of [lo, hi] in which some op ran on the device."""
     return sum(e - s for s, e in merged(
         ((s, s + d) for _, s, d in events["device"]), lo, hi))
+
+
+def mean_busy_ns(events: dict, lo: float, hi: float) -> float:
+    """``busy_ns`` averaged over the traced devices."""
+    busy = [busy_ns(dev, lo, hi) for dev in per_device(events)]
+    return sum(busy) / len(busy)
 
 
 def idle_gaps(events: dict, lo: float, hi: float):

@@ -87,14 +87,27 @@ def _leaf_init(path, shape, dtype, key):
     return (jax.random.normal(key, shape) * fan_in ** -0.5).astype(dtype)
 
 
-def make_params(model, key):
+def _placed(mesh, shardings):
+    """``jax.jit`` keywords that make a call's outputs on ``mesh`` in the
+    program's placement, ``shardings(rules)`` of ``repro.sharding.rules``,
+    so no chip ever holds the whole tree; none without a mesh."""
+    if mesh is None:
+        return {}
+    from repro.sharding import rules
+    return {"out_shardings": shardings(rules)}
+
+
+def make_params(model, key, mesh=None):
     """Base weights in the program's layout, made on the device in one jitted
     call.  Norm scales are 1 + 0.1 N(0, 1) and biases 0.1 N(0, 1), so a path
-    that skipped one would show; matrices are N(0, 1/fan_in)."""
+    that skipped one would show; matrices are N(0, 1/fan_in).  On a
+    ``mesh`` each chip makes only its share, in the program's placement;
+    the values are the same as without one."""
     shapes = jax.eval_shape(model.init, jax.random.key(0))
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
 
-    @jax.jit
+    @functools.partial(jax.jit, **_placed(
+        mesh, lambda rules: rules.params_sharding(shapes, mesh)))
     def init(key):
         return jax.tree_util.tree_unflatten(treedef, [
             _leaf_init(path, s.shape, s.dtype, jax.random.fold_in(key, i))
@@ -104,14 +117,19 @@ def make_params(model, key):
 
 
 def make_lora(cfg: dict, key, *, rank: int, targets, a_std: float,
-              b_std: float, lead=(), shared_lead: bool = False):
+              b_std: float, lead=(), shared_lead: bool = False, mesh=None):
     """Adapter weights {target: {"a", "b"}} with leading dims ``lead``.
     ``shared_lead``: every index of the leading dims gets the same values
-    (a federated job whose clients start from one adapter)."""
+    (a federated job whose clients start from one adapter).  On a ``mesh``
+    they are made in the program's placement of a client-stacked adapter."""
     shapes = family(cfg).lora_shapes(cfg, rank, targets,
                                       () if shared_lead else lead)
+    placement = lambda rules: rules.lora_sharding(jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.float32),
+        family(cfg).lora_shapes(cfg, rank, targets, lead),
+        is_leaf=lambda x: isinstance(x, tuple)), mesh)
 
-    @jax.jit
+    @functools.partial(jax.jit, **_placed(mesh, placement))
     def init(key):
         out = {}
         for i, t in enumerate(sorted(shapes)):

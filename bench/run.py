@@ -13,9 +13,12 @@ line of stdout is one JSON object: correct, attempted, failed, metrics,
 device (and breakdown when traced), then checks: every number compared with
 the reference beside its limit, which are also the last lines of stderr.
 
-Without a TPU, or with fewer chips than the cell asks for, or without the
-system under test (``src/repro``) beside it, the run exits non-zero and
-prints no result.
+A training mix may name a ``"mesh"`` (``"1x4"``): the cell then runs on a
+mesh of that shape over its chips.  A mesh whose size is not the cell's
+``chips``, or a cell on several chips whose mix names none, is refused (exit
+2) before any device work.  Without a TPU, or with fewer chips than the cell
+asks for, or without the system under test (``src/repro``) beside it, the
+run exits non-zero and prints no result.
 """
 import time
 
@@ -93,6 +96,11 @@ def main(argv=None) -> int:
     except ValueError as e:
         print(f"bench: {cell['config']}: {e}", file=sys.stderr)
         return 2
+    mix = traffic.load(cell["traffic"])
+    refused = harness.check_chips(cell, mix)
+    if refused:
+        print(f"bench: {args.workload}: {refused}", file=sys.stderr)
+        return 2
     cache = harness.use_compile_cache(ROOT)
     if jax.default_backend() != "tpu":
         print(f"bench: JAX found no TPU (backend {jax.default_backend()!r}); "
@@ -102,7 +110,6 @@ def main(argv=None) -> int:
         print(f"bench: {args.workload} needs {cell['chips']} chips, JAX sees "
               f"{jax.device_count()}", file=sys.stderr)
         return 3
-    mix = traffic.load(cell["traffic"])
     reported = {m["name"] for m in bench["end_to_end"]
                 if args.workload in m.get("workloads", [args.workload])}
     ctx = {"args": args, "cfg": cfg,

@@ -5,9 +5,15 @@ host and calls the compiled round engine (``make_run_chunk``) once per chunk.
 Set-up builds one trainer from the seed (base weights and the starting
 adapter made by the benchmark), runs its first chunk through the same
 ``run`` call and feed the window uses, and keeps what that chunk left
-behind: each round's loss and the change of every adapter leaf.  The window then times further chunks on the same object.
-After it, the reference repeats the first chunk's rounds from the same
-weights and data, and the two are compared.
+behind: each round's loss and the change of every adapter leaf.  The
+window then times further chunks on the same object.  After it, the
+reference repeats the first chunk's rounds from the same weights and data,
+and the two are compared.
+
+A mix with ``"mesh": "<DxM>"`` runs on that mesh over the cell's chips:
+the trainer gets the mesh, and the benchmark makes the base and the
+starting adapter in the program's placement, so no chip holds the whole
+base.
 """
 from __future__ import annotations
 
@@ -17,6 +23,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 import harness
 import model as bmodel
@@ -46,39 +53,60 @@ def round_flops(cfg: dict, mix: dict) -> float:
         seq=mix["seq_len"], rank=mix["rank"], targets=mix["targets"])
 
 
-def start_lora(cfg: dict, mix: dict, key):
+def start_lora(cfg: dict, mix: dict, key, *, lead=None, mesh=None):
     """The adapter every client starts from (FedSA: one shared A; B too is
     shared and non-zero, as when a federated job resumes from a pretrained
-    adapter, so that the first round already moves A)."""
+    adapter, so that the first round already moves A), stacked over the
+    clients (``lead``: other leading dims; ``()`` gives one client's), on
+    ``mesh`` in the program's placement."""
     return bmodel.make_lora(cfg, jax.random.fold_in(key, 2),
                             rank=mix["rank"], targets=mix["targets"],
                             a_std=mix["a_std"], b_std=mix["b_std"],
-                            lead=(mix["clients"],), shared_lead=True)
+                            lead=(mix["clients"],) if lead is None else lead,
+                            shared_lead=True, mesh=mesh)
+
+
+def mix_mesh(mix: dict):
+    """The mesh a mix names (``"mesh": "1x4"``), built by the program's own
+    ``mesh_from_spec`` over this machine's chips; None without the key."""
+    if "mesh" not in mix:
+        return None
+    from repro.launch.mesh import mesh_from_spec
+    return mesh_from_spec(mix["mesh"])
 
 
 @jax.jit
-def _norms(lo, l0):
+def _squares(lo, l0):
     return jnp.concatenate([
-        jnp.sqrt(jnp.sum(jnp.square(a - b), axis=(0, *range(2, a.ndim))))
+        jnp.sum(jnp.square(a - b), axis=(0, *range(2, a.ndim)))
         for a, b in zip(jax.tree.leaves(lo), jax.tree.leaves(l0))])
 
 
-def change_norms(lora, lora0) -> np.ndarray:
-    """Norm of every leaf's change (per leaf of the program's adapter tree
-    and per layer, over all clients), in the tree's order."""
-    return np.asarray(_norms(lora, lora0), np.float64)
+def change_norms(trees, lora0) -> np.ndarray:
+    """Norm of every leaf's change from ``lora0`` (one client's start, with
+    a leading dim of 1), per leaf of the program's adapter tree and per
+    layer, over all clients: ``trees`` are client-stacked trees in the
+    program's layout that together hold every client once.  In the tree's
+    order."""
+    return np.sqrt(sum(np.asarray(_squares(t, lora0), np.float64)
+                       for t in trees))
 
 
 def build(cfg: dict, mix: dict, seed: int, spans):
-    """The trainer and what set-up made for it."""
+    """The trainer and what set-up made for it.  On the mix's mesh the base
+    weights, the starting adapter and the optimizer state are made or put
+    in the program's placement (``repro.sharding.rules``), as the trainer's
+    own ``_place_on_mesh`` puts its state."""
     from repro.configs.base import (FederatedConfig, LoRAConfig,
                                     OptimizerConfig)
     from repro.core.federated import FederatedTrainer
     from repro.models.api import build_model
+    from repro.sharding import rules
     fam = bmodel.family(cfg)
     model = build_model(fam.program_config(cfg))
     key = bmodel.seed_key(seed)
-    params = fam.make_params(model, jax.random.fold_in(key, 1))
+    mesh = mix_mesh(mix)
+    params = fam.make_params(model, jax.random.fold_in(key, 1), mesh=mesh)
     n = mix["clients"]
     trainer = FederatedTrainer(
         model, TimedData(traffic.FederatedData(mix, cfg["vocab_size"], seed),
@@ -92,24 +120,32 @@ def build(cfg: dict, mix: dict, seed: int, spans):
                                 partition="dirichlet",
                                 dirichlet_alpha=mix["dirichlet_alpha"]),
         opt_cfg=OptimizerConfig(name=mix["optimizer"], lr=mix["lr"]),
-        base_params=params, chunk_rounds=mix["chunk_rounds"])
-    trainer.lora = fam.program_lora(start_lora(cfg, mix, key))
-    trainer.opt_state = {"t": jnp.zeros((n,), jnp.int32)}
-    return {"model": model, "params": params, "trainer": trainer, "key": key}
+        base_params=params, chunk_rounds=mix["chunk_rounds"], mesh=mesh)
+    # free the trainer's own adapter first: the two need not share a chip
+    trainer.lora = trainer.opt_state = None
+    trainer.lora = fam.program_lora(start_lora(cfg, mix, key, mesh=mesh))
+    opt = {"t": jnp.zeros((n,), jnp.int32)}
+    trainer.opt_state = (opt if mesh is None else
+                         jax.device_put(opt, rules.lora_sharding(opt, mesh)))
+    return {"model": model, "params": params, "trainer": trainer, "key": key,
+            "mesh": mesh}
 
 
 def first_chunk(cfg, mix, state, spans):
     """Run the first chunk through the window's own call; returns each
     round's loss and the leaf change norms after the chunk."""
     trainer = state["trainer"]
-    lora0 = bmodel.family(cfg).program_lora(
-        start_lora(cfg, mix, state["key"]))
     with spans.span("bench.run_chunk"):
         trainer.run(mix["chunk_rounds"])
+    lora0 = bmodel.family(cfg).program_lora(
+        start_lora(cfg, mix, state["key"], lead=(1,), mesh=state["mesh"]))
     out = {"loss": [h["loss"] for h in trainer.history],
-           "norms": change_norms(trainer.lora, lora0)}
+           "norms": change_norms([trainer.lora], lora0)}
     del lora0
     return out
+
+
+_add = jax.jit(lambda x, y: jax.tree.map(jnp.add, x, y))
 
 
 def reference_rounds(cfg, mix, params, seed, key, *, dtype="float32",
@@ -117,42 +153,57 @@ def reference_rounds(cfg, mix, params, seed, key, *, dtype="float32",
     """The first chunk's rounds computed by the reference: every client's
     local SGD steps on its own rows, then FedSA (the mean of A over the
     clients; each B stays with its client).  A round's loss is the mean
-    of each step's loss over clients and steps.  ``dtype="bfloat16"`` computes forward
-    and backward in bfloat16 (the control); ``skip_half`` leaves the second
-    half of the clients out of the round (a planted fault)."""
+    of each step's loss over clients and steps.  ``dtype="bfloat16"``
+    computes forward and backward in bfloat16 (the control); ``skip_half``
+    leaves the second half of the clients out of the round (a planted
+    fault).
+
+    It goes one client and one step at a time and keeps one A, the running
+    sum of the clients' new A and each client's B, so that it fits beside
+    the base.  Where ``params`` lie on a mesh, the adapters and rows are
+    replicated over it and the compiler partitions each step."""
     fam = bmodel.family(cfg)
     dt = jnp.dtype(dtype)
     gamma = traffic.sfedlora_gamma(mix)
     lr, n, steps = mix["lr"], mix["clients"], mix["local_steps"]
     data = traffic.FederatedData(mix, cfg["vocab_size"], seed)
-    lora0 = start_lora(cfg, mix, key)
+    first = jax.tree.leaves(params)[0].sharding
+    put = ((lambda t: jax.device_put(t, NamedSharding(first.mesh, P())))
+           if isinstance(first, NamedSharding) else (lambda t: t))
+    lora0 = put(start_lora(cfg, mix, key, lead=()))
     p = params if dt == jnp.float32 else fam.cast(params, dt)
     grad = jax.jit(jax.value_and_grad(
         lambda lo, p, toks: fam.loss(cfg, p, toks, fam.cast(lo, dt), gamma)))
     update = jax.jit(lambda lo, g: jax.tree.map(lambda x, y: x - lr * y,
                                                 lo, g))
     trained = n // 2 if skip_half else n
-    state = lora0
+    a = {t: lora0[t]["a"] for t in lora0}
+    bs = [{t: lora0[t]["b"] for t in lora0}] * n
+    del lora0
     out = {"loss": []}
     with jax.default_matmul_precision("highest"):
         for r in range(mix["chunk_rounds"]):
             batch = data.round_batch(steps)
-            losses, clients = [], []
+            losses, total = [], None
             for i in range(n):
-                lo = jax.tree.map(lambda x: x[i], state)
+                lo = {t: {"a": a[t], "b": bs[i][t]} for t in a}
                 for s in range(steps if i < trained else 0):
-                    loss, g = grad(lo, p, jnp.asarray(batch[i, s]))
+                    loss, g = grad(lo, p, put(jnp.asarray(batch[i, s])))
                     lo = update(lo, g)
                     losses.append(float(loss))
-                clients.append(lo)
-            stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *clients)
-            state = {t: {"a": jnp.broadcast_to(
-                         stacked[t]["a"][:trained].mean(0),
-                         stacked[t]["a"].shape),
-                         "b": stacked[t]["b"]} for t in stacked}
+                if i < trained:
+                    mine = {t: lo[t]["a"] for t in lo}
+                    total = mine if total is None else _add(total, mine)
+                bs[i] = {t: lo[t]["b"] for t in lo}
+                del lo
+            a = jax.tree.map(lambda x: x / trained, total)
+            del total
             out["loss"].append(float(np.mean(losses)))
-        out["norms"] = change_norms(fam.program_lora(state),
-                                    fam.program_lora(lora0))
+        one = lambda tree: fam.program_lora(jax.tree.map(
+            lambda x: x[None], tree))
+        out["norms"] = change_norms(
+            [one({t: {"a": a[t], "b": b[t]} for t in a}) for b in bs],
+            one(put(start_lora(cfg, mix, key, lead=()))))
     return out
 
 
@@ -227,16 +278,17 @@ def run(ctx) -> tuple[dict, dict, dict]:
             "setup_s": {"value": setup_s, "unit": "s"}}
         return result, checks, notes
 
-    events = harness.trace_events(ctx["trace_dir"])
+    events = harness.trace_events(ctx["trace_dir"], ctx["chips"])
     lo_hi = harness.slice_bounds(events)
     rctx = {"events": events, "slice": lo_hi, "spans": spans,
             "stage": stage, "rounds_in_slice": traced_rounds,
-            "round_flops": round_flops(cfg, mix),
+            "round_flops": round_flops(cfg, mix), "chips": ctx["chips"],
             "peaks": harness.peaks(device["kind"]), "cfg": cfg, "mix": mix}
     result["metrics"] = ctx["per_layer"](rctx)
     if lo_hi:
         lo, hi = lo_hi
-        result["device"]["busy_s"] = harness.busy_ns(events, lo, hi) / 1e9
+        result["device"]["busy_s"] = harness.mean_busy_ns(events, lo,
+                                                          hi) / 1e9
         result["device"]["window_s"] = (hi - lo) / 1e9
         result["breakdown"] = harness.breakdown(events, lo, hi)
     return result, checks, notes
