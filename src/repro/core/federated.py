@@ -38,6 +38,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +51,7 @@ from repro.core.quant import dequantize_tree, has_quantized
 from repro.core.lora import (AdapterSet, apply_rank_mask, init_lora,
                              mask_rank_tree, rank_mask)
 from repro.core.scaling import per_client_gammas, scaling_factor
+from repro.models.transformer import choose_saved
 from repro.optim.optimizers import apply_updates, global_norm, make_optimizer
 
 
@@ -60,6 +62,87 @@ def participation_weights(key, num_clients: int, num_sampled: int):
     return jnp.zeros((num_clients,), jnp.float32).at[perm[:num_sampled]].set(1.0)
 
 
+def device_bytes_limit():
+    """The bytes one device of the round may hold
+    (``memory_stats()["bytes_limit"]``), or None where its backend reports
+    none, as the CPU does."""
+    from repro.sharding.specs import current_mesh
+    mesh = current_mesh()
+    dev = (mesh.devices.flat[0] if mesh is not None
+           else jax.local_devices()[0])
+    return (dev.memory_stats() or {}).get("bytes_limit")
+
+
+def _nbytes(tree) -> int:
+    return sum(math.prod(x.shape) * jnp.dtype(x.dtype).itemsize
+               for x in jax.tree.leaves(tree))
+
+
+def _step_tokens(batches) -> int:
+    """Tokens one local step runs over all clients: ``batches`` leaves are
+    ``(N, local_steps, rows, seq)``."""
+    n, _, rows, seq = batches["tokens"].shape[:4]
+    return n * rows * seq
+
+
+def round_rest_bytes(model, base, lora_N, opt_N, batches) -> int:
+    """The device bytes a round can hold at once when its layer scans
+    keep only each layer's input, counted as the TPU's compiler counts
+    them (``memory_analysis().peak_memory_in_bytes``) and summed as if
+    all were live together: the arguments; a bfloat16 copy of each
+    float32 matrix of the base that is read only as a matmul operand,
+    made once per chunk; the adapters again, layer-major for the scan,
+    and their gradient; the head's logits, their softmax and their
+    gradient in float32, and the gradient's bfloat16 copy; the layer
+    inputs the scan keeps.  Shapes are global, so on a mesh this counts
+    the whole round on one device."""
+    cfg = model.cfg
+    tokens = _step_tokens(batches)
+    copies = sum(
+        math.prod(x.shape) * 2
+        for path, x in jax.tree_util.tree_flatten_with_path(base)[0]
+        if x.dtype == jnp.float32
+        and model.dot_only(tuple(k.key for k in path)))
+    logits = tokens * model.vocab_padded * (3 * 4 + 2)
+    inputs = (cfg.num_layers * tokens * cfg.d_model
+              * jnp.dtype(cfg.dtype).itemsize)
+    return (_nbytes(base) + 3 * _nbytes(lora_N) + _nbytes(opt_N)
+            + _nbytes(batches) + copies + logits + inputs)
+
+
+# The share of the device's bytes the chooser leaves unclaimed.  In
+# compiles for a described v5e a chosen set's peak came up to 0.24 GB over
+# the full-layer checkpoint's peak plus the set's bytes, and
+# round_rest_bytes counted that peak 0.5-2.4 GB high.
+SAVED_MARGIN = 0.01
+
+
+def saved_residuals(model, base, lora_N, opt_N, batches, limit):
+    """The tensors (``transformer.SAVEABLE``) that every client's layer
+    scan keeps for its backward in a round over ``batches``, and their
+    bytes: as many as fit ``limit`` bytes, less ``SAVED_MARGIN`` of it,
+    beside :func:`round_rest_bytes`.  No limit keeps none."""
+    if limit is None:
+        return (), 0
+    budget = (int(limit * (1 - SAVED_MARGIN))
+              - round_rest_bytes(model, base, lora_N, opt_N, batches))
+    return choose_saved(model.cfg, tokens=_step_tokens(batches),
+                        itemsize=jnp.dtype(model.cfg.dtype).itemsize,
+                        budget=budget)
+
+
+def _round_saved(model, base, lora_N, opt_N, batches):
+    """:func:`saved_residuals` on the device the round runs on, recorded
+    once per trace of the round: a span ``fed.saved`` (attr ``saved``,
+    the names) and the counter ``fed.saved_residual_bytes``."""
+    names, nbytes = saved_residuals(model, base, lora_N, opt_N, batches,
+                                    device_bytes_limit())
+    with trace.span("fed.saved", saved=list(names)):
+        if nbytes:
+            trace.count("fed.saved_residual_bytes", nbytes)
+    return names
+
+
 def _make_client_local(model, strat, opt_cfg):
     """The per-client local-training scan (``local_steps`` optimizer steps
     on one client's adapter state), shared by the synchronous and the
@@ -68,7 +151,7 @@ def _make_client_local(model, strat, opt_cfg):
     _, opt_update = make_optimizer(opt_cfg)
 
     def client_local(base, lora, opt_state, batches, round_idx, mask_row,
-                     gamma_i, gamma_static):
+                     gamma_i, gamma_static, saved=()):
         def step(carry, batch):
             lo, st = carry
             def loss_fn(l):
@@ -81,7 +164,7 @@ def _make_client_local(model, strat, opt_cfg):
                 aset = AdapterSet(
                     lora=l,
                     gamma=gamma_static if gamma_i is None else gamma_i)
-                return model.loss(base, batch, adapters=aset)
+                return model.loss(base, batch, adapters=aset, saved=saved)
             (loss, m), grads = jax.value_and_grad(loss_fn, has_aux=True)(lo)
             gnorm = global_norm(grads)
             grads = strat.mask_grads(grads, round_idx)
@@ -141,9 +224,11 @@ def make_round_body(model, *, strategy, opt_cfg, track_update_norm=False):
         g = adapters.gamma
         static = isinstance(g, (int, float))
         gamma_N = None if static else jnp.asarray(g, jnp.float32)
+        saved = _round_saved(model, base, lora_N, opt_N, batches)
         new_lora, new_opt, ms = jax.vmap(
             functools.partial(client_local,
-                              gamma_static=g if static else None),
+                              gamma_static=g if static else None,
+                              saved=saved),
             in_axes=(None, 0, 0, 0, None,
                      None if mask_N is None else 0,
                      None if gamma_N is None else 0))(
@@ -265,9 +350,11 @@ def make_buffered_round_body(model, *, strategy, opt_cfg, fault_model=None,
         # the client compute graph is the synchronous engine's graph
         static = isinstance(g, (int, float))
         gamma_N = None if static else jnp.asarray(g, jnp.float32)
+        saved = _round_saved(model, base, lora_N, opt_N, batches)
         new_lora, new_opt, ms = jax.vmap(
             functools.partial(client_local,
-                              gamma_static=g if static else None),
+                              gamma_static=g if static else None,
+                              saved=saved),
             in_axes=(None, 0, 0, 0, None,
                      None if mask_N is None else 0,
                      None if gamma_N is None else 0))(

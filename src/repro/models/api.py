@@ -98,11 +98,12 @@ class Model:
                     batched_scan_layout(tree))
         return tree
 
-    def forward(self, params, batch, adapters=None):
+    def forward(self, params, batch, adapters=None, *, saved=()):
         """Full-sequence forward.  Returns (logits, aux_loss).
 
         ``adapters`` is an :class:`repro.core.lora.AdapterSet` (or None for
-        the base model)."""
+        the base model).  ``saved``: the tensors of
+        ``transformer.SAVEABLE`` the layer scan keeps for a backward."""
         adapters = as_adapter_set(adapters)
         cfg = self.cfg
         with dispatch.scope(cfg.use_pallas):
@@ -114,18 +115,19 @@ class Model:
             x, aux = apply_stack(cfg, params["stack"], x,
                                  adapters=self._stack_adapters(adapters),
                                  positions=positions, enc_out=enc_out,
-                                 causal=cfg.family != "encoder")
+                                 causal=cfg.family != "encoder", saved=saved)
             x = apply_norm(cfg, x, params, "final")
             head = (params["embed"].T if cfg.tie_embeddings
                     else params["lm_head"])
             logits = x @ head.astype(x.dtype)
         return logits, aux
 
-    def loss(self, params, batch, adapters=None):
+    def loss(self, params, batch, adapters=None, *, saved=()):
         """Next-token CE over the text segment (+ MoE aux).  Encoder-only
         models use MLM-style loss (mask every 5th token).
 
-        ``adapters`` is an AdapterSet (or None for the base model)."""
+        ``adapters`` is an AdapterSet (or None for the base model);
+        ``saved`` as in :meth:`forward`."""
         adapters = as_adapter_set(adapters)
         cfg = self.cfg
         tokens = batch["tokens"]
@@ -135,7 +137,7 @@ class Model:
             masked_pos = (jnp.arange(s) % 5) == 2
             inp = jnp.where(masked_pos[None, :], mask_id, tokens)
             logits, aux = self.forward(params, {**batch, "tokens": inp},
-                                       adapters=adapters)
+                                       adapters=adapters, saved=saved)
             lf = logits.astype(jnp.float32)
             lse = jax.scipy.special.logsumexp(lf, axis=-1)
             ll = jnp.take_along_axis(lf, tokens[..., None], axis=-1)[..., 0]
@@ -144,8 +146,9 @@ class Model:
             return ce + aux, {"ce": ce, "aux": aux}
         from repro.sharding import opts
         if opts.enabled("chunked_ce"):
-            return self._loss_chunked(params, batch, adapters)
-        logits, aux = self.forward(params, batch, adapters=adapters)
+            return self._loss_chunked(params, batch, adapters, saved)
+        logits, aux = self.forward(params, batch, adapters=adapters,
+                                   saved=saved)
         s_text = tokens.shape[1]
         logits = logits[:, -s_text:][:, :-1]
         labels = tokens[:, 1:]
@@ -155,7 +158,8 @@ class Model:
         ce = (lse - ll).mean()
         return ce + aux, {"ce": ce, "aux": aux}
 
-    def _loss_chunked(self, params, batch, adapters, chunk: int = 512):
+    def _loss_chunked(self, params, batch, adapters, saved,
+                      chunk: int = 512):
         """CE computed in sequence chunks: the full (b, s, V) logits tensor
         never materializes — the head matmul + logsumexp + label gather run
         per chunk inside a scan (beyond-paper memory-term optimization)."""
@@ -170,7 +174,7 @@ class Model:
             x, aux = apply_stack(cfg, params["stack"], x,
                                  adapters=self._stack_adapters(adapters),
                                  positions=positions, enc_out=enc_out,
-                                 causal=cfg.family != "encoder")
+                                 causal=cfg.family != "encoder", saved=saved)
             x = apply_norm(cfg, x, params, "final")
         head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
         s_text = tokens.shape[1]

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.models import scan_unroll
 from repro.models.layers import apply_rope, linear, rms_norm
@@ -60,10 +61,14 @@ def _project_qkv(cfg, params, x, kv_x=None, adapters=None, positions=None,
     lv = (adapters or {}).get("v")
     q = linear(x, params["q"], lq).reshape(b, s, cfg.num_heads, cfg.head_dim)
     k = linear(kv_x, params["k"], lk).reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-    v = linear(kv_x, params["v"], lv).reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+    v = checkpoint_name(linear(kv_x, params["v"], lv).reshape(
+        b, t, cfg.num_kv_heads, cfg.head_dim), "v")
+    # q and k are named where the backward's last read of them is: the
+    # norm's backward reads its input; past rotary only the attention's
+    # matmuls read them, as operands the TPU keeps in bfloat16
     if cfg.qk_norm:
-        q = rms_norm(q, params["q_norm_scale"])
-        k = rms_norm(k, params["k_norm_scale"])
+        q = rms_norm(checkpoint_name(q, "q"), params["q_norm_scale"])
+        k = rms_norm(checkpoint_name(k, "k"), params["k_norm_scale"])
     if use_rope:
         if positions is None:
             positions = jnp.arange(s)[None, :]
@@ -71,6 +76,8 @@ def _project_qkv(cfg, params, x, kv_x=None, adapters=None, positions=None,
             kv_positions = jnp.arange(t)[None, :]
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, kv_positions, cfg.rope_theta)
+    if not cfg.qk_norm:
+        q, k = checkpoint_name(q, "q"), checkpoint_name(k, "k")
     return q, k, v
 
 
@@ -196,7 +203,8 @@ def attention_fullseq(cfg, params, x, *, causal=True, adapters=None,
                          window=win if causal else None)
         out = attention_core(cfg, q, k, v, mask)
     lo = (adapters or {}).get("o")
-    return linear(out.reshape(b, s, -1), params["o"], lo)
+    return checkpoint_name(linear(out.reshape(b, s, -1), params["o"], lo),
+                           "attn_out")
 
 
 # ----------------------------------------------------------------- KV cache prefill

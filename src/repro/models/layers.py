@@ -3,6 +3,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.kernels.dispatch import lora_linear
 
@@ -86,11 +87,13 @@ def mlp_apply(cfg, params, x, adapters=None):
     three GEMMs route through ``linear`` so a quantized frozen base
     (core/quant.py packed leaves) hits the dequant-in-VMEM kernel tier; with
     fp leaves ``linear`` reduces to the same single XLA GEMM as before."""
-    up = linear(x, params["w_up"])
+    up = checkpoint_name(linear(x, params["w_up"]), "mlp_up")
     if cfg.mlp_variant == "swiglu":
-        h = jax.nn.silu(linear(x, params["w_gate"])) * up
+        gate = checkpoint_name(linear(x, params["w_gate"]), "mlp_gate")
+        h = jax.nn.silu(gate) * up
     elif cfg.mlp_variant == "geglu":
-        h = jax.nn.gelu(linear(x, params["w_gate"]), approximate=True) * up
+        gate = checkpoint_name(linear(x, params["w_gate"]), "mlp_gate")
+        h = jax.nn.gelu(gate, approximate=True) * up
     else:
         h = jax.nn.gelu(up, approximate=True)
     return linear(h, params["w_down"])

@@ -373,13 +373,57 @@ def reset_paged_blocks(cache, blocks):
     return _map_paged_cache(cache, None, pool, lambda c, v, axis: c)
 
 
+# The per-layer tensors that the backward of a frozen base reads, named with
+# ``checkpoint_name``: the q, k and v projections (attention.py, where the
+# backward last reads them), the attention's output projection, and the
+# MLP's gate and up (layers.py).  Keeping one spares the backward its
+# matmul; down's output is read by no backward, so it has no name.
+SAVEABLE = ("q", "k", "v", "attn_out", "mlp_gate", "mlp_up")
+
+
+def saveable_widths(cfg):
+    """``{name: (width, d_in)}`` per token and layer for a scanned stack of
+    dense attention blocks: keeping one element of ``name`` spares the
+    backward ``2 * d_in`` recomputed FLOPs.  Empty for any other stack."""
+    if (cfg.family != "dense" or cfg.moe is not None
+            or tuple(cfg.block_pattern) != ("attn",)):
+        return {}
+    d = cfg.d_model
+    widths = {"q": (cfg.q_dim, d), "k": (cfg.kv_dim, d),
+              "v": (cfg.kv_dim, d), "attn_out": (d, cfg.q_dim),
+              "mlp_up": (cfg.d_ff, d)}
+    if cfg.mlp_variant in ("swiglu", "geglu"):
+        widths["mlp_gate"] = (cfg.d_ff, d)
+    return {n: widths[n] for n in SAVEABLE if n in widths}
+
+
+def choose_saved(cfg, *, tokens: int, itemsize: int, budget: int):
+    """The names a layer scan over ``tokens`` tokens a step keeps, and their
+    bytes: saveable tensors taken greedily by recomputed FLOPs spared per
+    byte kept (ties in ``SAVEABLE`` order), each only while the total over
+    every scanned layer stays within ``budget`` bytes."""
+    layers = stack_layout(cfg.num_layers, cfg.block_pattern)[0]
+    widths = saveable_widths(cfg)
+    order = sorted(widths, key=lambda n: -widths[n][1])
+    names, total = [], 0
+    for n in order:
+        nbytes = layers * tokens * widths[n][0] * itemsize
+        if total + nbytes <= budget:
+            names.append(n)
+            total += nbytes
+    return tuple(sorted(names, key=SAVEABLE.index)), total
+
+
 def apply_stack(cfg, stack_params, x, *, adapters=None, positions=None,
-                causal=True, pattern=None, remat=True, enc_out=None):
+                causal=True, pattern=None, remat=True, saved=(),
+                enc_out=None):
     """Full-sequence forward.  Returns (x, aux_sum).
 
     ``adapters`` is the prepared "stack" subtree of an AdapterSet (scaling
     folded, mask applied); banked per-request trees must be in scan layout
-    (see :func:`batched_scan_layout`)."""
+    (see :func:`batched_scan_layout`).  With ``remat`` each scanned layer
+    keeps its input for the backward, and the tensors of ``SAVEABLE`` that
+    ``saved`` names; the backward recomputes the rest of the layer."""
     pattern = pattern or cfg.block_pattern
     adapters = adapters or {}
     rep_p = stack_params.get("repeat", {})
@@ -408,7 +452,18 @@ def apply_stack(cfg, stack_params, x, *, adapters=None, positions=None,
             body = jax.checkpoint(
                 one_rep,
                 policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        elif remat and saved:
+            # no CSE barrier: inside a scan the backward's recomputation
+            # cannot merge with the forward anyway, and with tensors kept
+            # the barrier cost more than they spared (a TPU v5e round of
+            # stablelm-1.6b at N 8: 273 ms without it, 304 with, 302 with
+            # nothing kept)
+            body = jax.checkpoint(
+                one_rep, prevent_cse=False,
+                policy=jax.checkpoint_policies.save_only_these_names(*saved))
         elif remat:
+            # with nothing kept the barrier stays: without it that round
+            # ran 310 ms against 302
             body = jax.checkpoint(one_rep)
         else:
             body = one_rep
