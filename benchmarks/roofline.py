@@ -132,11 +132,8 @@ def quant_decode_table(emit=print):
     buffers).  Decode at small batch is bandwidth-bound: every step streams
     the whole parameter set once, so predicted per-token intensity is
     2*P*B FLOPs over the tree's stored bytes, and the predicted decode
-    speedup from quantization is simply the byte ratio.  When
-    BENCH_serve.json carries a ``quant`` section the MEASURED decode ratio
-    prints beside the prediction (CPU container: XLA re-dequantizes on the
-    reference tier, so measured ~1.0x is expected there; the predicted
-    column is the TPU story the packed DMA path exists for)."""
+    speedup from quantization is simply the byte ratio.  No decode ratio
+    has been measured on a chip, so the measured column prints ``-``."""
     import jax
     import numpy as np
 
@@ -156,15 +153,6 @@ def quant_decode_table(emit=print):
                    for l in jax.tree_util.tree_leaves(trees["fp"]))
     flops = 2 * n_params * batch
 
-    measured = {}
-    bench = os.path.join(os.path.dirname(__file__), "..", "BENCH_serve.json")
-    try:
-        with open(bench) as f:
-            measured = {k: v.get("decode_vs_fp")
-                        for k, v in json.load(f).get("quant", {}).items()}
-    except (OSError, ValueError):
-        pass
-
     emit("roofline,quant,mode,base_mbytes,total_mbytes,intensity_flops_per_"
          "byte,pred_decode_speedup,measured_decode_vs_fp")
     fp_bytes = foot["fp"]["total_bytes"]
@@ -176,7 +164,7 @@ def quant_decode_table(emit=print):
                "total_mbytes": fo["total_bytes"] / 1e6,
                "intensity": flops / fo["total_bytes"],
                "pred_decode_speedup": fp_bytes / fo["total_bytes"],
-               "measured_decode_vs_fp": measured.get(mode)}
+               "measured_decode_vs_fp": None}
         rows.append(row)
         meas = (f"{row['measured_decode_vs_fp']:.2f}"
                 if row["measured_decode_vs_fp"] else "-")

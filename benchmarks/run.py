@@ -12,8 +12,8 @@ import subprocess
 import sys
 import time
 
-SUITES = ("comm", "kernels", "engine", "serve", "roofline", "fig9", "fig3",
-          "fig2", "fig4", "fig8", "tab12", "table")
+SUITES = ("comm", "kernels", "engine", "roofline", "fig9", "fig3", "fig2",
+          "fig4", "fig8", "tab12", "table")
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -131,10 +131,6 @@ def main() -> None:
         from benchmarks import engine_bench
         run("engine_bench", engine_bench.main,
             **({"rounds": rounds} if rounds else {}))
-    if "serve" in want:
-        from benchmarks import serve_bench
-        run("serve_bench", serve_bench.main,
-            **({"steps": 8} if fast else {}))
     if "roofline" in want:
         from benchmarks import roofline
         run("roofline", roofline.main)

@@ -566,7 +566,7 @@ class AdapterBank:
         The new set is prepared (rank-masked, gamma folded into B) and
         zero-padded to the bank's ``r_max``, so the stacked leaves keep
         EXACTLY their shapes and dtypes: every executable compiled against
-        the bank (decode chunks, admission prefills, the fixed engine) stays
+        the bank (decode chunks, admission prefills) stays
         valid — swapping a slot triggers zero recompiles (asserted in
         tests/test_lifecycle.py).  A set whose rank exceeds ``r_max`` is
         rejected rather than silently reshaping the bank.

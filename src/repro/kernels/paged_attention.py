@@ -1,8 +1,8 @@
 """Pallas-TPU paged-attention decode kernel.
 
 Continuous batching stores KV state in a SHARED block pool
-(num_blocks, block_size, kh, hd) instead of per-request ring buffers; each
-request's blocks are named by a row of the block table (B, blocks_per_req).
+(num_blocks, block_size, kh, hd); each request's blocks are named by a row
+of the block table (B, blocks_per_req).
 The reference tier materializes a request's view with an XLA gather
 (``models/attention.py::paged_gather``) — an HBM copy of the whole working
 set every decode step.  This kernel never materializes it: the K/V/pos
@@ -20,15 +20,14 @@ virtual ring.
 Numerics: the streaming accumulation is mathematically exact but not
 bit-identical to the one-shot softmax of the gather path, so the engine
 routes here only on the compiled ``pallas`` tier (``dispatch.resolve_mode``)
-— interpret/reference-tier serving keeps the gather path, which is what the
-scheduled-vs-fixed-batch token-identity guarantee is stated over.  Parity
-with :func:`repro.kernels.ref.paged_attention_ref` is asserted to fp32
+— interpret/reference-tier serving keeps the gather path.  Parity with
+:func:`repro.kernels.ref.paged_attention_ref` is asserted to fp32
 tolerance in tests/test_paged.py.
 
 Validity masking needs no extra operand: the pos pool rides along as a
 third table-indexed input, and an entry is attendable iff
-``0 <= pos <= qpos`` (and within the sliding window) — exactly the ring
-cache's mask formula.
+``0 <= pos <= qpos`` (and within the sliding window) — exactly the gather
+path's mask formula.
 """
 from __future__ import annotations
 

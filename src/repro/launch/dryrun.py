@@ -139,16 +139,17 @@ def _build(arch: str, shape_name: str, mesh, rank: int, alpha: float,
         return prefill, (params_s, batch), in_shard
 
     # decode
-    def serve_step(params, cache, token, pos):
-        return model.decode_step(params, cache, token, pos)
+    def serve_step(params, cache, token, pos, table):
+        return model.decode_step(params, cache, token, pos, table=table)
     params_s = jax.eval_shape(lambda: model.init(jax.random.key(0)))
     spec = model.input_specs(shape)
     in_shard = (rules.params_sharding(params_s, mesh),
                 rules.cache_sharding(spec["cache"], mesh),
                 rules.inputs_sharding(spec["token"], mesh),
-                rules.inputs_sharding(spec["pos"], mesh))
-    return (serve_step, (params_s, spec["cache"], spec["token"], spec["pos"]),
-            in_shard)
+                rules.inputs_sharding(spec["pos"], mesh),
+                rules.inputs_sharding(spec["table"], mesh))
+    return (serve_step, (params_s, spec["cache"], spec["token"], spec["pos"],
+                         spec["table"]), in_shard)
 
 
 def _compile_stats(arch, shape_name, mesh, rank, alpha, *, num_layers=None,

@@ -1,19 +1,33 @@
 """Multi-tenant batched LoRA serving from an AdapterBank.
 
-Generation is a DEVICE-RESIDENT engine: one ``model.prefill`` fills the KV
-cache over the whole prompt in a single batched forward, then a ``lax.scan``
-decode loop carries (cache, token, PRNG key) entirely on device — greedy and
-temperature sampling happen inside the scan, so a whole generation is ONE
-host dispatch instead of one per token.  The signature is uniform across the
-base / single-adapter / bank paths because the adapters travel as one value.
+One engine serves every request: the continuous-batching scheduler
+:func:`serve_scheduled`.  It serves a STREAM of requests over a fixed set of
+engine slots:
 
-The bank path uses ``AdapterBank.requests(ids)`` — the LAZY per-request
-view: adapter leaves stay tenant-stacked and each projection gathers its own
-rows (in-kernel via the BGMV tier's ids-indexed BlockSpecs on fused tiers),
-so serving K heterogeneous-rank tenants never materializes per-request
-copies of the bank.
+  * KV state lives in per-layer SHARED block pools (``init_paged_cache``)
+    addressed through a per-slot block table.  :class:`BlockPool` hands
+    blocks out and takes them back on the host, so a finished request's
+    memory is reusable the moment it completes.
+  * Admission is one jitted prefill per same-length newcomer group
+    (:func:`_jit_paged_admit`) on a VIEW whose pools ARE the engine pools
+    and whose per-slot state is fresh (``transformer.paged_prefill_view``);
+    merging scatters the newcomers' slot state back without touching
+    continuing requests.  It emits each newcomer's first token.
+  * Decode runs in CHUNKS (:func:`_jit_paged_chunk`): one jitted lax.scan
+    of ``chunk`` greedy steps over all engine slots, active or not — idle
+    slots' table rows point at the reserved null block 0, so their
+    discarded writes land where no live request ever looks.  Between chunks
+    the host admits newly-arrived requests into free slots and evicts
+    finished ones.
 
-  # fresh random adapters (API smoke):
+Decoding is greedy over the real vocabulary.  The bank's adapters reach the
+engine through ``AdapterBank.requests(ids)`` — the LAZY per-request view:
+adapter leaves stay tenant-stacked and each projection gathers its own rows
+(in-kernel via the BGMV tier's ids-indexed BlockSpecs on fused tiers), so
+serving K heterogeneous-rank tenants never materializes per-request copies
+of the bank.
+
+  # a burst of --batch requests over fresh random adapters (API smoke):
   PYTHONPATH=src python -m repro.launch.serve --arch gemma-2b --reduced \
       --steps 16 --batch 8 --clients 4
 
@@ -22,10 +36,12 @@ copies of the bank.
   PYTHONPATH=src python -m repro.launch.serve --arch gemma-2b --reduced \
       --resume /tmp/ck.npz --steps 16 --batch 8
 
-The classic zero-overhead single-tenant path (merge one client's adapters
-into the base weights) remains available via ``--merge CLIENT``.  The old
-token-by-token host loop survives as ``generate_hostloop`` — the parity
-oracle the compiled engine is tested against, and serve_bench's baseline.
+  # a seeded Poisson request stream:
+  PYTHONPATH=src python -m repro.launch.serve --arch gemma-2b --reduced \
+      --arrival-trace poisson:20:32 --steps 16
+
+``--merge CLIENT`` folds one client's adapters into the base weights and
+serves that single tenant with no adapter work at all.
 """
 from __future__ import annotations
 
@@ -71,28 +87,7 @@ def _model_jit(model, name: str, builder):
     return fn
 
 
-def _jit_decode_step(model):
-    """One jitted decode step per Model instance: ``model.decode_step`` is
-    a fresh bound-method object on every attribute access, so an inline
-    ``jax.jit(model.decode_step)`` would build a new executable cache per
-    call and recompile every time the generator is re-entered."""
-    return _model_jit(model, "decode_step",
-                     lambda m: jax.jit(m.decode_step))
-
-
-def _jit_banked_step(model):
-    """One jitted bank-gathering decode step per Model instance (the
-    host-loop oracle's banked path; the compiled engine gathers lazily)."""
-    def build(m):
-        @jax.jit
-        def step(params, cache, tok, pos, bank, ids):
-            return m.decode_step(params, cache, tok, pos,
-                                 adapters=bank.gather(ids))
-        return step
-    return _model_jit(model, "banked_step", build)
-
-
-# ------------------------------------------------------------ compiled engine
+# ------------------------------------------------------------ engine programs
 
 def _prepare_base(m, params):
     """Loop-invariant handling of a packed frozen base (core/quant.py).
@@ -164,8 +159,8 @@ def serving_base(m, params):
 
 
 def _prepare_adapters(m, adapters):
-    """Loop-invariant adapter preparation, shared by every compiled engine
-    entry point: gamma folds, rank masking, the bank's per-request gather,
+    """Loop-invariant adapter preparation, shared by both engine programs:
+    gamma folds, rank masking, the bank's per-request gather,
     and the (K, layers) -> (layers, K) scan relayout all run ONCE per
     compiled call — left inside decode_step they re-run EVERY token (XLA
     does not hoist the relayout transposes or gathers out of a scan;
@@ -184,177 +179,7 @@ def _prepare_adapters(m, adapters):
     return None if tree is None else AdapterSet(lora={"stack": tree})
 
 
-def _sample(logits, key, temperature: float, vocab: int):
-    """One next token per row from (b, V) logits.  ``temperature`` is a
-    static float: 0.0 compiles to pure greedy (no RNG ops in the graph).
-    Both branches slice off the padded vocab rows (``V`` is ``vocab_padded``
-    and the untrained padding logits are nonzero — random-normal embed
-    init), so emitted ids are always real tokens; the host-loop oracle
-    slices identically, keeping the engines bit-comparable."""
-    logits = logits[..., :vocab]
-    if temperature == 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jax.random.categorical(
-        key, logits.astype(jnp.float32) / temperature).astype(jnp.int32)
-
-
-def _compiled_generate(model):
-    """The device-resident generation program, jitted once per model:
-    prefill over the prompt, then a lax.scan decode loop whose carry
-    (cache, token, key) never leaves the device."""
-    def build(m):
-        def run(params, prompt, adapters, key, *, steps, max_len,
-                temperature):
-            b, p = prompt.shape
-            vocab = m.cfg.vocab_size
-            params = _prepare_base(m, params)
-            adapters = _prepare_adapters(m, adapters)
-            cache = m.init_cache(b, max_len)
-            logits, cache = m.prefill(params, cache, prompt, adapters,
-                                      last_only=True)
-            key, k0 = jax.random.split(key)
-            tok = _sample(logits[:, -1], k0, temperature, vocab)[:, None]
-
-            def step(carry, pos):
-                cache, tok, key = carry
-                lg, cache = m.decode_step(params, cache, tok,
-                                          jnp.full((b,), pos), adapters)
-                key, kt = jax.random.split(key)
-                nxt = _sample(lg[:, -1], kt, temperature, vocab)[:, None]
-                return (cache, nxt, key), nxt[:, 0]
-
-            (cache, _, _), rest = jax.lax.scan(
-                step, (cache, tok, key),
-                jnp.arange(p, p + steps - 1, dtype=jnp.int32))
-            return jnp.concatenate(
-                [prompt.astype(jnp.int32), tok, rest.T], axis=1)
-        return jax.jit(run, static_argnames=("steps", "max_len",
-                                             "temperature"))
-    return _model_jit(model, "generate", build)
-
-
-def generate(model, params, prompt, steps: int, max_len: int, adapters=None,
-             *, temperature: float = 0.0, key=None):
-    """Compiled generation: ``steps`` tokens after the prompt in ONE host
-    dispatch (batched prefill + on-device scan decode).
-
-    ``adapters``: None (base / merged weights), a single AdapterSet, or a
-    banked per-request set (``AdapterBank.requests``/``gather``) — the
-    signature is uniform because the adapters travel as one value.
-    ``temperature`` 0.0 decodes greedily; > 0.0 samples inside the scan
-    from ``key`` (defaults to a fixed key for reproducibility).
-    Returns the (b, p + steps) sequence, prompt included."""
-    if steps < 1:
-        raise ValueError(f"steps must be >= 1, got {steps}")
-    prompt = jnp.asarray(prompt)
-    if key is None:
-        key = jax.random.key(0)
-    run = _compiled_generate(model)
-    trace.count("serve.dispatches")
-    return run(params, prompt, adapters, key, steps=int(steps),
-               max_len=int(max_len), temperature=float(temperature))
-
-
-# Host-boundary validation of request->tenant ids against a bank of ``size``
-# tenants (shared with AdapterBank.gather/requests; traced ids pass through).
-_check_adapter_ids = check_adapter_ids
-
-
-def generate_banked(model, params, bank: AdapterBank, adapter_ids, prompt,
-                    steps: int, max_len: int, *, temperature: float = 0.0,
-                    key=None):
-    """Multi-tenant compiled generation: row i of ``prompt`` is served with
-    adapter ``adapter_ids[i]``.  The ids are traced, so one executable
-    covers every tenant mix; the bank leaves stay stacked and each
-    projection (or the BGMV kernel) gathers its own request rows."""
-    _check_adapter_ids(adapter_ids, bank.size)
-    return generate(model, params, prompt, steps, max_len,
-                    adapters=bank.requests(adapter_ids),
-                    temperature=temperature, key=key)
-
-
-# ---------------------------------------------------------- host-loop oracle
-
-def generate_hostloop(model, params, prompt, steps: int, max_len: int,
-                      adapters=None):
-    """The pre-engine token-by-token loop (one jitted dispatch per token,
-    prompt fed through single-token decode steps) — kept as the parity
-    oracle for the compiled engine and as serve_bench's baseline.  Greedy
-    argmax slices to the real vocab exactly like the compiled engine, so
-    the two stay bit-comparable AND neither emits padded-vocab ids."""
-    b, p = prompt.shape
-    vocab = model.cfg.vocab_size
-    cache = model.init_cache(b, max_len)
-    step = _jit_decode_step(model)
-    tok = prompt[:, :1]
-    out = [tok]
-    for t in range(p + steps - 1):
-        trace.count("serve.dispatches")
-        logits, cache = step(params, cache, tok, jnp.full((b,), t),
-                             adapters)
-        nxt = (prompt[:, t + 1:t + 2] if t + 1 < p
-               else jnp.argmax(logits[:, -1:, :vocab],
-                               -1).astype(jnp.int32))
-        out.append(nxt)
-        tok = nxt
-    return jnp.concatenate(out, axis=1)
-
-
-def generate_banked_hostloop(model, params, bank: AdapterBank, adapter_ids,
-                             prompt, steps: int, max_len: int):
-    """Host-loop oracle for the bank path (materialized per-step gather)."""
-    _check_adapter_ids(adapter_ids, bank.size)
-    b, p = prompt.shape
-    vocab = model.cfg.vocab_size
-    cache = model.init_cache(b, max_len)
-    step = _jit_banked_step(model)
-    ids = jnp.asarray(adapter_ids, jnp.int32)
-    tok = prompt[:, :1]
-    out = [tok]
-    for t in range(p + steps - 1):
-        trace.count("serve.dispatches")
-        logits, cache = step(params, cache, tok, jnp.full((b,), t), bank, ids)
-        nxt = (prompt[:, t + 1:t + 2] if t + 1 < p
-               else jnp.argmax(logits[:, -1:, :vocab],
-                               -1).astype(jnp.int32))
-        out.append(nxt)
-        tok = nxt
-    return jnp.concatenate(out, axis=1)
-
-
 # ----------------------------------------------- continuous-batching scheduler
-#
-# The fixed-batch engine above serves ONE batch per compiled call: every
-# request in the batch starts together, decodes in lockstep, and the whole
-# batch holds its ring-buffer KV cache until the LAST request finishes.  At
-# mixed lengths / staggered arrivals that is the classic head-of-line
-# problem: a request arriving just after a batch launched waits a full
-# generation, and a short request pins its cache rows while long neighbors
-# drag on.
-#
-# The scheduler below serves a STREAM of requests through a paged engine:
-#
-#   * KV state lives in per-layer SHARED block pools (model.init_paged_cache)
-#     addressed through a per-slot block table — BlockPool hands blocks out
-#     and takes them back on the host, so a finished request's memory is
-#     reusable the moment it completes, not when its batch drains.
-#   * Decode runs in CHUNKS: one jitted lax.scan of `chunk` steps over all
-#     engine slots (active or not — idle slots' table rows point at the
-#     reserved null block 0, so their discarded writes land where no live
-#     request ever looks).  Between chunks the host admits newly-arrived
-#     requests into free slots and evicts finished ones.
-#   * Admission is one jitted prefill per same-length newcomer group on a
-#     VIEW whose pools ARE the engine pools and whose per-slot state is
-#     fresh (transformer.paged_prefill_view); merging scatters the
-#     newcomers' slot state back without touching continuing requests.
-#
-# At a static schedule (every request present at t=0, uniform shapes) the
-# admission group IS the fixed-engine batch and every chunk step runs the
-# same program on the same shapes, so scheduled greedy decode is
-# token-identical to `generate` on the gather tiers (tests/test_paged.py);
-# under staggered arrivals it trades nothing for the latency win that
-# benchmarks/serve_bench.py measures.
-
 
 class BlockPool:
     """Host-side free-list allocator over the paged cache's block axis.
@@ -398,7 +223,7 @@ class BlockPool:
 @dataclasses.dataclass
 class Request:
     """One generation request for the scheduler.  ``steps`` counts generated
-    tokens (prompt excluded), matching `generate`; ``arrival`` is seconds
+    tokens (prompt excluded); ``arrival`` is seconds
     from scheduler start.  ``adapter_id`` is the TENANT identity — a row of
     a static AdapterBank, or a store tenant of a LiveAdapterBank (which may
     live in host RAM until this request promotes it); it is validated at
@@ -536,19 +361,19 @@ def serve_scheduled(model, params, requests, *, bank=None, max_batch=4,
         # may legitimately grow mid-run (a publish from on_boundary), so
         # its tenants are checked at admission time instead.
         for r in reqs:
-            _check_adapter_ids([r.adapter_id], bank.size,
-                               what=f"request rid={r.rid}: adapter_id")
+            check_adapter_ids([r.adapter_id], bank.size,
+                              what=f"request rid={r.rid}: adapter_id")
     need = max(len(r.prompt) + r.steps for r in reqs)
     max_len = max_len or need
     win = model.cfg.attn_window
     # a sliding-window model may wrap its virtual ring (vlen = blocks *
-    # block_size) exactly like the fixed engine's ring cache, as long as
-    # the ring still covers the window
+    # block_size) as long as the ring still covers the window
     if need > max_len and (win is None or max_len < win):
         raise ValueError(f"request needs {need} positions > max_len "
                          f"{max_len}")
-    # per-request virtual ring sized exactly like the fixed engine's ring
-    # cache (window-bounded), so the paged layout stays element-identical
+    # each request owns a virtual ring covering max_len positions, or the
+    # window on a sliding-window model: position p lives in ring slot
+    # p % vlen, vlen = mb * block_size
     ring = min(max_len, win) if win else max_len
     mb = -(-ring // block_size)
     pool = BlockPool(1 + max_batch * mb)
@@ -747,7 +572,7 @@ def make_requests(trace, *, prompt_len, steps, tenants, vocab, seed=0,
     """Request list from an arrival trace.
 
     ``trace`` is either ``poisson:RATE:N`` (N arrivals, RATE req/s, seeded
-    exponential inter-arrival gaps — the serve_bench scenario) or a path to
+    exponential inter-arrival gaps) or a path to
     a JSON list of ``{"arrival": s, "steps": n, "adapter": k, "deadline":
     d}`` records.  Prompts are seeded random ids, round-robin adapters
     unless the trace names them.  ``deadline_steps`` is the default
@@ -822,10 +647,11 @@ def main(argv=None):
     ap.add_argument("--alpha", type=float, default=8.0)
     ap.add_argument("--scaling", default="sfedlora",
                     choices=("lora", "rslora", "sfedlora", "za", "zb"))
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--steps", type=int, default=16)
-    ap.add_argument("--temperature", type=float, default=0.0,
-                    help="0 = greedy; > 0 samples inside the compiled scan")
+    ap.add_argument("--batch", type=int, default=4,
+                    help="without --arrival-trace: this many requests, all "
+                         "arrived at time 0")
+    ap.add_argument("--steps", type=int, default=16,
+                    help="generated tokens per request (greedy)")
     ap.add_argument("--clients", type=int, default=4,
                     help="tenant count for a fresh bank (ignored with "
                          "--resume: every checkpointed client serves)")
@@ -843,13 +669,13 @@ def main(argv=None):
     ap.add_argument("--merge", type=int, default=None, metavar="CLIENT",
                     help="classic single-tenant path: merge this client's "
                          "adapters into the base weights (zero serving "
-                         "overhead) instead of banked decode")
+                         "overhead) and serve every request from it")
     ap.add_argument("--arrival-trace", default=None,
-                    help="serve a request STREAM through the continuous-"
-                         "batching scheduler instead of one fixed batch: "
-                         "'poisson:RATE:N' (seeded Poisson arrivals) or a "
-                         "JSON trace file of {arrival, steps, adapter} "
-                         "records")
+                    help="serve a request STREAM with arrivals honored "
+                         "against the wall clock instead of a burst at "
+                         "time 0: 'poisson:RATE:N' (seeded Poisson "
+                         "arrivals) or a JSON trace file of {arrival, "
+                         "steps, adapter} records")
     ap.add_argument("--max-batch", type=int, default=4,
                     help="scheduler engine slots (concurrent requests)")
     ap.add_argument("--block-size", type=int, default=8,
@@ -887,42 +713,20 @@ def _serve(args):
     # packed checkpoint under a mismatched --quant is a hard error)
     src = (f"checkpoint '{args.resume}'" if args.resume else "fresh base")
     base = apply_quant_flag(base, args.quant, args.quant_group, source=src)
-    prompt = jax.random.randint(jax.random.key(2), (args.batch, 4), 0,
-                                cfg.vocab_size)
-    max_len = 4 + args.steps
-
     if args.arrival_trace:
         reqs = make_requests(args.arrival_trace, prompt_len=4,
                              steps=args.steps, tenants=bank.size,
                              vocab=cfg.vocab_size,
                              deadline_steps=args.deadline_steps)
-        serve_bank = bank
-        if args.hot_slots:
-            serve_bank = LiveAdapterBank.from_bank(bank,
-                                                   hot_slots=args.hot_slots)
-        t0 = time.monotonic()
-        done = serve_scheduled(model, base, reqs, bank=serve_bank,
-                               max_batch=args.max_batch,
-                               block_size=args.block_size, chunk=args.chunk)
-        dt = time.monotonic() - t0
-        lats = sorted(r.t_done - r.arrival for r in done
-                      if r.t_done is not None)
-        p50 = lats[len(lats) // 2] if lats else 0.0
-        p99 = lats[min(len(lats) - 1, int(len(lats) * 0.99))] if lats else 0.0
-        toks = sum(len(r.tokens) for r in done)
-        n_to = sum(1 for r in done if r.timed_out)
-        print(f"# {args.arch} scheduled serve: {len(done)} requests, "
-              f"{bank.size} tenants, max_batch={args.max_batch} "
-              f"block={args.block_size} chunk={args.chunk}  "
-              f"p50={p50*1000:.0f}ms p99={p99*1000:.0f}ms "
-              f"goodput={toks/dt:.1f} tok/s"
-              + (f" timeouts={n_to}" if args.deadline_steps else ""))
-        if args.hot_slots:
-            print(f"# live bank: {serve_bank.hot_slots}/{len(serve_bank.tenants)} "
-                  f"slots hot, {serve_bank.promotions} promotions, "
-                  f"{serve_bank.demotions} demotions")
-        return done
-
+    else:
+        # a burst: seeded 4-token prompts, tenants round-robin over the bank
+        prompts = np.asarray(jax.random.randint(
+            jax.random.key(2), (args.batch, 4), 0, cfg.vocab_size), np.int32)
+        reqs = [Request(rid=i, prompt=prompts[i], steps=args.steps,
+                        adapter_id=i % bank.size,
+                        deadline_steps=args.deadline_steps)
+                for i in range(args.batch)]
+    serve_bank = bank
     if args.merge is not None:
         merged = bank.adapter(args.merge).merge(base)
         if has_quantized(base):
@@ -930,34 +734,35 @@ def _serve(args):
             # re-pack onto the checkpoint's grid or --merge --quant would
             # silently serve fp weights and lose the whole footprint win
             merged = requantize_merged(merged, base)
-        seq = generate(model, merged, prompt, args.steps, max_len,
-                       temperature=args.temperature)  # warm-up + compile
-        t0 = time.monotonic()
-        seq = jax.block_until_ready(
-            generate(model, merged, prompt, args.steps, max_len,
-                     temperature=args.temperature))
-        dt = time.monotonic() - t0
-        print(f"# {args.arch} merged tenant {args.merge}: "
-              f"batch={args.batch} steps={args.steps}  "
-              f"{dt*1000/args.steps:.1f} ms/token (compiled engine)")
-        print(seq[:, :12])
-        return seq
-
-    ids = jnp.arange(args.batch) % bank.size
-    seq = generate_banked(model, base, bank, ids, prompt, args.steps,
-                          max_len, temperature=args.temperature)
+        base, serve_bank = merged, None
+    elif args.hot_slots:
+        serve_bank = LiveAdapterBank.from_bank(bank, hot_slots=args.hot_slots)
     t0 = time.monotonic()
-    seq = jax.block_until_ready(
-        generate_banked(model, base, bank, ids, prompt, args.steps, max_len,
-                        temperature=args.temperature))
+    done = serve_scheduled(model, base, reqs, bank=serve_bank,
+                           max_batch=args.max_batch,
+                           block_size=args.block_size, chunk=args.chunk,
+                           wait=bool(args.arrival_trace))
     dt = time.monotonic() - t0
-    print(f"# {args.arch} banked decode: {bank.size} tenants "
-          f"(ranks {','.join(str(r) for r in bank.ranks)}), "
-          f"batch={args.batch} steps={args.steps}  "
-          f"{dt*1000/args.steps:.1f} ms/token (compiled engine, "
-          f"1 dispatch/call)")
-    print(seq[:, :12])
-    return seq
+    lats = sorted(r.t_done - r.arrival for r in done if r.t_done is not None)
+    p50 = lats[len(lats) // 2] if lats else 0.0
+    p99 = lats[min(len(lats) - 1, int(len(lats) * 0.99))] if lats else 0.0
+    toks = sum(len(r.tokens) for r in done)
+    n_to = sum(1 for r in done if r.timed_out)
+    served = (f"merged tenant {args.merge}" if args.merge is not None
+              else f"{bank.size} tenants (ranks "
+                   f"{','.join(str(r) for r in bank.ranks)})")
+    print(f"# {args.arch} scheduled serve: {len(done)} requests, {served}, "
+          f"max_batch={args.max_batch} block={args.block_size} "
+          f"chunk={args.chunk}  "
+          + (f"p50={p50*1000:.0f}ms p99={p99*1000:.0f}ms "
+             if args.arrival_trace else "")
+          + f"goodput={toks/dt:.1f} tok/s"
+          + (f" timeouts={n_to}" if args.deadline_steps else ""))
+    if isinstance(serve_bank, LiveAdapterBank):
+        print(f"# live bank: {serve_bank.hot_slots}/{len(serve_bank.tenants)} "
+              f"slots hot, {serve_bank.promotions} promotions, "
+              f"{serve_bank.demotions} demotions")
+    return done
 
 
 if __name__ == "__main__":

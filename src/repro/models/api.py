@@ -1,6 +1,6 @@
 """Public model API: build_model(cfg) -> Model with init / forward / loss /
-init_cache / decode_step, uniform across all families (dense, moe, hybrid,
-ssm, vlm, audio)."""
+init_paged_cache / prefill / decode_step, uniform across all families
+(dense, moe, hybrid, ssm, vlm, audio)."""
 from __future__ import annotations
 
 import jax
@@ -13,7 +13,7 @@ from repro.models.layers import norm_params, apply_norm
 from repro.models.transformer import (apply_stack, banked_scan_layout,
                                       batched_scan_layout, decode_stack,
                                       init_stack, init_paged_stack_cache,
-                                      init_stack_cache, prefill_stack)
+                                      prefill_stack)
 
 PATCH_EMBED_DIM = 1152   # SigLIP stub output width (arXiv:2407.07726)
 
@@ -204,43 +204,42 @@ class Model:
         return ce + aux, {"ce": ce, "aux": aux}
 
     # ------------------------------------------------------------- serving
-    def init_cache(self, batch: int, max_len: int, dtype=None):
-        cfg = self.cfg
-        dtype = dtype or jnp.dtype(cfg.dtype)
-        cross = cfg.encoder_frames if cfg.family == "audio" else 0
-        return init_stack_cache(cfg, batch, max_len, dtype, cross_len=cross)
-
     def init_paged_cache(self, num_blocks: int, block_size: int, batch: int,
                          dtype=None):
-        """Paged serving cache: per-layer KV pools of ``num_blocks`` x
+        """Serving cache: per-layer KV pools of ``num_blocks`` x
         ``block_size`` slots shared by every request through per-request
         block tables, plus per-slot recurrent/cross state for ``batch``
-        engine slots.  Block 0 is reserved as the null block idle slots
-        write into (see launch/serve.py's allocator)."""
+        engine slots.  The scheduler reserves block 0 as the null block
+        idle slots write into (see launch/serve.py's allocator)."""
         cfg = self.cfg
         dtype = dtype or jnp.dtype(cfg.dtype)
         cross = cfg.encoder_frames if cfg.family == "audio" else 0
         return init_paged_stack_cache(cfg, num_blocks, block_size, batch,
                                       dtype, cross_len=cross)
 
-    def prefill(self, params, cache, tokens, adapters=None, *, enc_out=None,
-                last_only=False, table=None):
-        """Whole-prompt forward that fills a FRESH cache in one batched
-        pass: tokens (b, p) int32 -> (logits (b, p, V), new_cache).
+    def prefill(self, params, cache, tokens, adapters=None, *, table,
+                enc_out=None, last_only=False):
+        """Whole-prompt forward that fills the requests' cache in one
+        batched pass: tokens (b, p) int32 -> (logits (b, p, V), new_cache).
         ``last_only=True`` projects only the final position through the
         lm head (logits (b, 1, V)) — generation consumes just that row, and
         at real vocab scale the head GEMM over every prompt position is the
         prefill's dominant wasted work.
 
-        The cache comes back as ``p`` sequential :meth:`decode_step` calls
-        would have left it (KV ring-buffer slots, recurrence states, conv
-        tails), so generation is one prefill + a decode loop instead of
-        feeding the prompt through single-token steps.  ``adapters`` as in
-        decode_step — None, an AdapterSet, or a banked per-request set from
+        ``cache`` is an :meth:`init_paged_cache` pool whose per-slot state
+        is fresh for these ``b`` requests, and ``table`` (b,
+        blocks_per_req) int32 names each request's pool blocks.  The token
+        at position t lands in virtual-ring slot ``t % vlen`` (vlen =
+        blocks_per_req * block_size): pool block ``table[i, (t % vlen) //
+        block_size]``, offset ``(t % vlen) % block_size``.  The cache comes
+        back as ``p`` sequential :meth:`decode_step` calls would have left
+        it (pool blocks, recurrence states, conv tails), so generation is
+        one prefill + a decode loop instead of feeding the prompt through
+        single-token steps.  ``adapters`` as in decode_step — None, an
+        AdapterSet, or a banked per-request set from
         ``AdapterBank.gather``/``requests``.  Encoder-decoder (audio)
         models pass the encoder output as ``enc_out`` so the per-layer
-        cross K/V land in the cache.  A paged cache (``init_paged_cache``)
-        additionally needs the requests' block ``table``."""
+        cross K/V land in the cache."""
         adapters = as_adapter_set(adapters)
         cfg = self.cfg
         with dispatch.scope(cfg.use_pallas):
@@ -261,14 +260,16 @@ class Model:
         return logits, new_cache
 
     def decode_step(self, params, cache, token, pos, adapters=None, *,
-                    table=None):
+                    table):
         """One token: token (b,1) int32, pos (b,) absolute position.
         Returns (logits (b,1,V), new_cache).
 
-        ``adapters`` may be a single AdapterSet or a ``batched`` one from
-        ``AdapterBank.gather`` (one adapter per batch row — multi-tenant
-        serving).  A paged cache additionally needs the requests' block
-        ``table`` (b, blocks_per_req) int32."""
+        ``cache`` is an :meth:`init_paged_cache` pool and ``table`` (b,
+        blocks_per_req) int32 each request's blocks in it, as in
+        :meth:`prefill`; the step writes position ``pos`` into its
+        virtual-ring slot and attends over the ring.  ``adapters`` may be a
+        single AdapterSet or a ``batched`` one from ``AdapterBank.gather``
+        (one adapter per batch row — multi-tenant serving)."""
         adapters = as_adapter_set(adapters)
         cfg = self.cfg
         with dispatch.scope(cfg.use_pallas):
@@ -315,12 +316,17 @@ class Model:
             return batch_spec(b, s)
         if shape.kind == "prefill":
             return batch_spec(shape.global_batch, shape.seq_len)
-        # decode: one token + cache of seq_len
+        # decode: one token against a pool holding each request's virtual
+        # ring of seq_len positions (the window, where one caps it) in
+        # blocks of 8; every slot is live, so the plan's table is
+        # arange(b * mb).reshape(b, mb) and needs no null block
         b = shape.global_batch
+        ring = min(shape.seq_len, cfg.attn_window or shape.seq_len)
+        mb = -(-ring // 8)
         cache = jax.eval_shape(
-            lambda: self.init_cache(b, shape.seq_len, dtype=dt))
+            lambda: self.init_paged_cache(b * mb, 8, b, dtype=dt))
         return {"token": sds((b, 1), i32), "pos": sds((b,), i32),
-                "cache": cache}
+                "table": sds((b, mb), i32), "cache": cache}
 
 
 def build_model(cfg) -> Model:

@@ -207,97 +207,22 @@ def attention_fullseq(cfg, params, x, *, causal=True, adapters=None,
                            "attn_out")
 
 
-# ----------------------------------------------------------------- KV cache prefill
-
-def fill_kv_cache(cache, k, v, positions):
-    """Write a whole prompt's K/V rows into the ring-buffer cache at the
-    slots the token-by-token decode would have used (``pos % size``).  When
-    the prompt overflows a sliding-window cache, only the last ``size``
-    positions land — exactly the survivors of sequential ring writes."""
-    size = cache["k"].shape[1]
-    if k.shape[1] > size:
-        k, v, positions = k[:, -size:], v[:, -size:], positions[:, -size:]
-    slots = positions % size
-    bidx = jnp.arange(k.shape[0])[:, None]
-    return {"k": cache["k"].at[bidx, slots].set(k),
-            "v": cache["v"].at[bidx, slots].set(v),
-            "pos": cache["pos"].at[bidx, slots].set(positions)}
-
-
-def attention_prefill(cfg, params, x, cache, positions, *, adapters=None):
-    """Whole-prompt attention that also fills the KV cache — the batched
-    form of running ``attention_decode`` once per prompt token on a FRESH
-    cache.  x (b, s, d), positions (b, s).  Returns (out, new_cache)."""
-    b, s, _ = x.shape
-    q, k, v = _project_qkv(cfg, params, x, adapters=adapters,
-                           positions=positions, kv_positions=positions)
-    new_cache = fill_kv_cache(cache, k, v, positions)
-    win = cfg.attn_window
-    q, k, v = _maybe_expand_kv(cfg, q, k, v)
-    if s > BLOCKWISE_THRESHOLD:
-        out = blockwise_attention(cfg, q, k, v, positions, positions,
-                                  causal=True, window=win)
-    else:
-        mask = make_mask(positions, positions, causal=True, window=win)
-        out = attention_core(cfg, q, k, v, mask)
-    y = linear(out.reshape(b, s, -1), params["o"],
-               (adapters or {}).get("o"))
-    return y, new_cache
-
-
-# ----------------------------------------------------------------- KV cache decode
-
-def init_kv_cache(cfg, batch: int, max_len: int, dtype):
-    """Per-layer cache: ring buffer when cfg.attn_window is set."""
-    size = min(max_len, cfg.attn_window) if cfg.attn_window else max_len
-    return {
-        "k": jnp.zeros((batch, size, cfg.num_kv_heads, cfg.head_dim), dtype),
-        "v": jnp.zeros((batch, size, cfg.num_kv_heads, cfg.head_dim), dtype),
-        "pos": jnp.full((batch, size), -1, jnp.int32),
-    }
-
-
-def attention_decode(cfg, params, x, cache, pos, *, adapters=None):
-    """One-token decode.  x (b,1,d); pos (b,) current absolute position.
-
-    Returns (out (b,1,d), new_cache).  Ring-buffer writes for sliding window.
-    """
-    b = x.shape[0]
-    size = cache["k"].shape[1]
-    q, k, v = _project_qkv(cfg, params, x, adapters=adapters,
-                           positions=pos[:, None], kv_positions=pos[:, None])
-    slot = pos % size                                   # (b,)
-    bidx = jnp.arange(b)
-    new_k = cache["k"].at[bidx, slot].set(k[:, 0])
-    new_v = cache["v"].at[bidx, slot].set(v[:, 0])
-    new_pos = cache["pos"].at[bidx, slot].set(pos)
-    valid = new_pos >= 0
-    mask = make_mask(pos[:, None], new_pos, causal=True,
-                     window=cfg.attn_window, valid_kv=valid)
-    out = attention_core(cfg, q, new_k, new_v, mask)
-    lo = (adapters or {}).get("o")
-    y = linear(out.reshape(b, 1, -1), params["o"], lo)
-    return y, {"k": new_k, "v": new_v, "pos": new_pos}
-
-
 # ----------------------------------------------------------------- paged KV cache
 #
-# The paged layout replaces the per-request ring buffer (batch, size, kh, hd)
-# with a SHARED block pool (num_blocks, block_size, kh, hd) plus a per-request
-# block table (b, blocks_per_req) int32 mapping virtual block j of request i
-# to a pool block.  A request's view of the pool is a virtual ring of
-# vlen = blocks_per_req * block_size slots: token at absolute position p
-# lands in virtual slot p % vlen, i.e. pool block table[i, (p % vlen) //
-# block_size] at offset (p % vlen) % block_size.  Because that is the exact
-# ring formula with vlen in place of size, gathering a request's blocks back
-# into (b, vlen, kh, hd) reproduces the ring-buffer layout element for
-# element — when block_size divides the ring size the paged decode is
-# bit-identical to the ring decode (tests/test_paged.py).
+# Serving keeps K/V in a SHARED block pool (num_blocks, block_size, kh, hd)
+# per layer, plus a per-request block table (b, blocks_per_req) int32
+# mapping virtual block j of request i to a pool block.  A request's view of
+# the pool is a virtual ring of vlen = blocks_per_req * block_size slots:
+# the token at absolute position p lands in virtual slot p % vlen, i.e. pool
+# block table[i, (p % vlen) // block_size] at offset (p % vlen) %
+# block_size.  A ring at least as long as the attention window (or the whole
+# request) loses nothing attention reads: a slot is overwritten only by the
+# position vlen later, by which time the old one has left the window.
 #
 # Block 0 is the NULL block: the scheduler points idle batch slots' table
 # rows at it, so their (discarded) decode writes land harmlessly in a block
 # no live request ever owns.  The pos pool doubles as the validity mask
-# (entry >= 0 == written), exactly like the ring cache's pos array.
+# (entry >= 0 == written).
 
 
 def init_paged_kv_cache(cfg, num_blocks: int, block_size: int, dtype):
@@ -325,8 +250,8 @@ def paged_gather(cache, table):
 
 
 def fill_paged_kv_cache(cache, k, v, positions, table):
-    """Paged counterpart of :func:`fill_kv_cache`: write a whole prompt's
-    K/V rows into each request's pool blocks at the virtual-ring slots the
+    """Write a whole prompt's K/V rows into each request's pool blocks at
+    their virtual-ring slots (``positions % vlen``), the slots the
     token-by-token decode would have used.  On overflow only the last
     ``vlen`` positions land — the survivors of sequential ring writes."""
     bs = cache["k_pool"].shape[1]
@@ -343,9 +268,10 @@ def fill_paged_kv_cache(cache, k, v, positions, table):
 
 def attention_prefill_paged(cfg, params, x, cache, positions, table, *,
                             adapters=None):
-    """Whole-prompt attention that fills the request's POOL blocks.  The
-    attention itself is over the prompt's own K/V (same math as
-    :func:`attention_prefill`); only the cache writes differ."""
+    """Whole-prompt attention that fills the requests' POOL blocks — the
+    batched form of running :func:`attention_decode_paged` once per prompt
+    token on empty blocks.  The attention itself is over the prompt's own
+    K/V.  x (b, s, d), positions (b, s).  Returns (out, new_cache)."""
     b, s, _ = x.shape
     q, k, v = _project_qkv(cfg, params, x, adapters=adapters,
                            positions=positions, kv_positions=positions)
@@ -368,8 +294,8 @@ def attention_decode_paged(cfg, params, x, cache, table, pos, *,
     """One-token decode against the block pool.  x (b,1,d); table (b,
     blocks_per_req) int32; pos (b,) absolute position.
 
-    Reference tier gathers the request's blocks back into the ring layout
-    and reuses the exact ring mask/attention ops (bit-identity); on the
+    Reference tier gathers each request's blocks into its virtual ring
+    (:func:`paged_gather`) and masks by the gathered positions; on the
     pallas tier the gather never materializes — the kernel's BlockSpecs
     stream pool blocks through the block table via scalar prefetch."""
     from repro.kernels import dispatch

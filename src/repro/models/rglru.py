@@ -82,7 +82,7 @@ def rglru_apply_fullseq(cfg, params, x, adapters=None):
     return (out @ params["w_out"].astype(jnp.float32)).astype(x.dtype)
 
 
-def rglru_init_cache(cfg, batch, dtype):
+def rglru_init_state(cfg, batch, dtype):
     dr = cfg.rglru_d_state or cfg.d_model
     return {"h": jnp.zeros((batch, dr), jnp.float32),
             "conv_tail": jnp.zeros((batch, CONV_WIDTH - 1, dr), dtype)}

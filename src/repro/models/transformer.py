@@ -5,10 +5,11 @@ A model is a stack of blocks drawn from ``cfg.block_pattern`` (repeated to
 (stacked params — keeps HLO size independent of depth); the remainder runs
 unrolled.  Every block kind supports:
 
-  init_block(cfg, key, kind)                      -> params pytree
-  apply_block(..., mode="fullseq")                -> (x, aux)
-  init_block_cache(cfg, kind, batch, max_len)     -> cache pytree
-  apply_block(..., mode="decode", cache=, pos=)   -> (x, aux, cache)
+  init_block(cfg, key, kind)                             -> params pytree
+  apply_block(..., mode="fullseq")                       -> (x, aux)
+  init_paged_block_cache(cfg, kind, num_blocks,
+                         block_size, batch, dtype)       -> cache pytree
+  apply_block(..., mode="decode", cache=, pos=, table=)  -> (x, aux, cache)
 
 Kinds: "attn" (GQA attention + MLP/MoE), "xattn" (self+cross attention + MLP,
 for encoder-decoder), "rglru" (RG-LRU temporal mix + MLP), "mlstm", "slstm".
@@ -25,9 +26,8 @@ from repro.models import attention as attn_mod
 from repro.models import rglru as rglru_mod
 from repro.models import xlstm as xlstm_mod
 from repro.models.attention import (attn_params, attention_fullseq,
-                                    attention_decode, attention_decode_paged,
-                                    attention_prefill,
-                                    attention_prefill_paged, init_kv_cache,
+                                    attention_decode_paged,
+                                    attention_prefill_paged,
                                     init_paged_kv_cache, _project_qkv,
                                     attention_core, make_mask)
 from repro.models.layers import (apply_norm, linear, mlp_apply, mlp_params,
@@ -68,32 +68,13 @@ def init_block(cfg, key, kind: str):
     return p
 
 
-def init_block_cache(cfg, kind: str, batch: int, max_len: int, dtype,
-                     cross_len: int = 0):
-    if kind == "attn":
-        return init_kv_cache(cfg, batch, max_len, dtype)
-    if kind == "xattn":
-        return {"self": init_kv_cache(cfg, batch, max_len, dtype),
-                "cross_k": jnp.zeros((batch, cross_len, cfg.num_kv_heads,
-                                      cfg.head_dim), dtype),
-                "cross_v": jnp.zeros((batch, cross_len, cfg.num_kv_heads,
-                                      cfg.head_dim), dtype)}
-    if kind == "rglru":
-        return rglru_mod.rglru_init_cache(cfg, batch, dtype)
-    if kind == "mlstm":
-        return xlstm_mod.mlstm_init_cache(cfg, batch, dtype)
-    if kind == "slstm":
-        return xlstm_mod.slstm_init_cache(cfg, batch, dtype)
-    raise ValueError(kind)
-
-
 def init_paged_block_cache(cfg, kind: str, num_blocks: int, block_size: int,
                            batch: int, dtype, cross_len: int = 0):
-    """Paged-serving counterpart of :func:`init_block_cache`: attention KV
-    moves into a shared block pool (no batch dim — requests own pool blocks
-    through the block table), while recurrent blocks and cross-attention K/V
-    carry constant-size PER-SLOT state (``batch`` = engine slot count) —
-    admit/evict for those is a slot-level state swap, not paging."""
+    """Serving cache of one block: attention KV lives in a shared block
+    pool (no batch dim — requests own pool blocks through the block table),
+    while recurrent blocks and cross-attention K/V carry constant-size
+    PER-SLOT state (``batch`` = engine slot count) — admit/evict for those
+    is a slot-level state swap, not paging."""
     if kind == "attn":
         return init_paged_kv_cache(cfg, num_blocks, block_size, dtype)
     if kind == "xattn":
@@ -103,7 +84,13 @@ def init_paged_block_cache(cfg, kind: str, num_blocks: int, block_size: int,
                                       cfg.head_dim), dtype),
                 "cross_v": jnp.zeros((batch, cross_len, cfg.num_kv_heads,
                                       cfg.head_dim), dtype)}
-    return init_block_cache(cfg, kind, batch, 0, dtype, cross_len=cross_len)
+    if kind == "rglru":
+        return rglru_mod.rglru_init_state(cfg, batch, dtype)
+    if kind == "mlstm":
+        return xlstm_mod.mlstm_init_state(cfg, batch, dtype)
+    if kind == "slstm":
+        return xlstm_mod.slstm_init_state(cfg, batch, dtype)
+    raise ValueError(kind)
 
 
 # ----------------------------------------------------------------- block apply
@@ -138,10 +125,9 @@ def apply_block(cfg, kind, p, x, *, adapters=None, positions=None,
     have), "decode" (one token against the cache).  Prefill and decode
     return (x, aux, new_cache); fullseq returns (x, aux).
 
-    A paged attention cache (``k_pool`` pool leaves instead of per-request
-    ``k`` rings — see models/attention.py) routes to the paged prefill /
-    decode paths; ``table`` (b, blocks_per_req) int32 is required then and
-    ignored otherwise.  Non-attention state is per-slot either way."""
+    Attention K/V live in the block pool (models/attention.py), addressed
+    through ``table`` (b, blocks_per_req) int32; prefill and decode of an
+    attention block need it.  Non-attention state is per-slot."""
     adapters = adapters or {}
     aux = jnp.zeros((), jnp.float32)
     h1 = apply_norm(cfg, x, p, "ln1")
@@ -150,29 +136,18 @@ def apply_block(cfg, kind, p, x, *, adapters=None, positions=None,
     if kind in ("attn", "xattn"):
         self_cache = (None if cache is None
                       else cache["self"] if kind == "xattn" else cache)
-        paged = self_cache is not None and "k_pool" in self_cache
         if mode == "fullseq":
             a = attention_fullseq(cfg, p["attn"], h1, causal=causal,
                                   adapters=adapters.get("attn"),
                                   positions=positions)
         elif mode == "prefill":
-            if paged:
-                a, self_cache = attention_prefill_paged(
-                    cfg, p["attn"], h1, self_cache, positions, table,
-                    adapters=adapters.get("attn"))
-            else:
-                a, self_cache = attention_prefill(
-                    cfg, p["attn"], h1, self_cache, positions,
-                    adapters=adapters.get("attn"))
+            a, self_cache = attention_prefill_paged(
+                cfg, p["attn"], h1, self_cache, positions, table,
+                adapters=adapters.get("attn"))
         else:
-            if paged:
-                a, self_cache = attention_decode_paged(
-                    cfg, p["attn"], h1, self_cache, table, pos,
-                    adapters=adapters.get("attn"))
-            else:
-                a, self_cache = attention_decode(
-                    cfg, p["attn"], h1, self_cache,
-                    pos, adapters=adapters.get("attn"))
+            a, self_cache = attention_decode_paged(
+                cfg, p["attn"], h1, self_cache, table, pos,
+                adapters=adapters.get("attn"))
         x = x + a
         if kind == "xattn":
             hx = apply_norm(cfg, x, p, "lnx")
@@ -266,27 +241,9 @@ def init_stack(cfg, key, *, num_layers=None, pattern=None):
     return out
 
 
-def init_stack_cache(cfg, batch, max_len, dtype, *, num_layers=None,
-                     pattern=None, cross_len=0):
-    num_layers = num_layers or cfg.num_layers
-    pattern = pattern or cfg.block_pattern
-    repeats, tail = stack_layout(num_layers, pattern)
-    mk = lambda kind: init_block_cache(cfg, kind, batch, max_len, dtype,
-                                       cross_len=cross_len)
-    out = {"repeat": {}, "tail": {}}
-    if repeats:
-        for j, kind in enumerate(pattern):
-            out["repeat"][f"p{j}"] = jax.tree.map(
-                lambda a: jnp.broadcast_to(a, (repeats,) + a.shape).copy(),
-                mk(kind))
-    for i, kind in enumerate(tail):
-        out["tail"][f"t{i}"] = mk(kind)
-    return out
-
-
 def init_paged_stack_cache(cfg, num_blocks, block_size, batch, dtype, *,
                            num_layers=None, pattern=None, cross_len=0):
-    """Stack cache for the paged serving engine: attention layers hold
+    """Stack cache for the serving engine: attention layers hold
     SHARED pools (repeat leaves gain a leading layer dim as usual), every
     other layer kind holds per-slot state for ``batch`` engine slots."""
     num_layers = num_layers or cfg.num_layers
@@ -342,7 +299,7 @@ def paged_prefill_view(cfg, cache, batch, dtype, *, num_layers=None,
                        pattern=None, cross_len=0):
     """Admission view: engine pools shared, fresh per-slot state for a
     ``batch``-request newcomer group (zero recurrences, -1e30 stabilizer
-    states — exactly what a fresh fixed-batch cache would hold)."""
+    states — what a request's first decode step starts from)."""
     fresh = init_paged_stack_cache(cfg, 1, 1, batch, dtype,
                                    num_layers=num_layers, pattern=pattern,
                                    cross_len=cross_len)
@@ -491,10 +448,10 @@ def prefill_stack(cfg, stack_params, cache, x, positions, *, adapters=None,
     """Whole-prompt forward that also fills every block cache in ONE pass —
     the batched replacement for feeding the prompt through single-token
     decode steps.  Returns (x, aux_sum, new_cache); the cache comes back
-    exactly as the token-by-token decode would have left it (KV ring-buffer
-    slots or pool blocks, recurrence states, conv tails).  ``table`` routes
-    paged attention caches; it is the same for every layer (each layer has
-    its own pool of identical geometry), so it rides the scan closure."""
+    exactly as the token-by-token decode would have left it (pool blocks,
+    recurrence states, conv tails).  ``table`` addresses the attention
+    pools; it is the same for every layer (each layer has its own pool of
+    identical geometry), so it rides the scan closure."""
     pattern = pattern or cfg.block_pattern
     adapters = adapters or {}
     rep_p = stack_params.get("repeat", {})

@@ -139,7 +139,7 @@ def mlstm_apply_prefill(cfg, params, x, cache, positions, adapters=None):
     return out, {"C": C, "n": n, "m": m}
 
 
-def mlstm_init_cache(cfg, batch, dtype):
+def mlstm_init_state(cfg, batch, dtype):
     h, hd = cfg.num_heads, cfg.head_dim
     return {"C": jnp.zeros((batch, h, hd, hd), jnp.float32),
             "n": jnp.zeros((batch, h, hd), jnp.float32),
@@ -253,7 +253,7 @@ def slstm_apply_prefill(cfg, params, x, cache, positions, adapters=None):
     return out, {"h": h, "c": c, "n": n, "m": m}
 
 
-def slstm_init_cache(cfg, batch, dtype):
+def slstm_init_state(cfg, batch, dtype):
     d = cfg.d_model
     z = jnp.zeros((batch, d), jnp.float32)
     return {"h": z, "c": z, "n": jnp.full((batch, d), 1e-6, jnp.float32),

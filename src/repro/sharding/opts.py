@@ -38,11 +38,6 @@ OPTS = {
     # dominates decode collective terms); the tied head pays a small
     # (b, 1, V) psum instead.
     "embed_dshard": False,
-    # decode-path: shard the KV cache's sequence dim over `model` (instead of
-    # kv-heads/head-dim).  Attention then computes per-shard partial scores
-    # and GSPMD combines via a tiny (b,h,1,S) gather + psum instead of
-    # all-gathering the hd-sharded cache (~134MB/layer for gemma decode).
-    "cache_seq_shard": False,
 }
 
 

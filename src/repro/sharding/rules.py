@@ -6,8 +6,9 @@ Policy (DESIGN.md §6):
     MoE stacks); stacked layer dims replicated.
   - LoRA: A replicated (the aggregated client-shared object), B model-sharded
     on d_out; leading client dim over ("pod","data").
-  - batch dims over ("pod","data"); decode caches: batch if divisible, else
-    the cache sequence dim; kv-heads over "model" when divisible.
+  - batch dims over ("pod","data"); decode caches: the KV pool's block dim
+    (per-slot state's batch dim) if divisible; kv-heads, else head dim,
+    over "model" when divisible.
 
 Every rule checks divisibility and degrades to replication, so the same code
 serves the 16x16 pod, the 2x16x16 multi-pod, and 1-device CPU tests.
@@ -143,26 +144,14 @@ def cache_spec(path_keys, shape, mesh) -> P:
     bdim = off                                # batch dim position
     bsz = shape[bdim] if nd > bdim else 0
     batch_ok = ba and _div(bsz, mesh, ba)
-    if leaf in ("k", "v"):                    # (b, S, kh, hd)
-        from repro.sharding.opts import enabled
-        if batch_ok:
+    if leaf in ("k_pool", "v_pool", "pos_pool"):  # (blocks, bs[, kh, hd])
+        if batch_ok:                          # the block dim, like a batch
             spec[bdim] = ba if len(ba) > 1 else ba[0]
-        elif _div(shape[off + 1], mesh, ba):
-            spec[off + 1] = ba if len(ba) > 1 else ba[0]   # seq-sharded cache
-        if enabled("cache_seq_shard") and spec[off + 1] is None and                 _div(shape[off + 1], mesh, "model"):
-            spec[off + 1] = "model"
-        elif _div(shape[off + 2], mesh, "model"):
-            spec[off + 2] = "model"
-        elif _div(shape[off + 3], mesh, "model"):
-            spec[off + 3] = "model"
-    elif leaf == "pos":                       # (b, S)
-        from repro.sharding.opts import enabled
-        if batch_ok:
-            spec[bdim] = ba if len(ba) > 1 else ba[0]
-        elif _div(shape[off + 1], mesh, ba):
-            spec[off + 1] = ba if len(ba) > 1 else ba[0]
-        if enabled("cache_seq_shard") and spec[off + 1] is None and                 _div(shape[off + 1], mesh, "model"):
-            spec[off + 1] = "model"
+        if leaf != "pos_pool":
+            if _div(shape[off + 2], mesh, "model"):
+                spec[off + 2] = "model"
+            elif _div(shape[off + 3], mesh, "model"):
+                spec[off + 3] = "model"
     elif leaf in ("cross_k", "cross_v"):
         if batch_ok:
             spec[bdim] = ba if len(ba) > 1 else ba[0]

@@ -135,11 +135,12 @@ def test_merge_equals_runtime(tiny):
 
 # ------------------------------------------------- LoRA-aware decode parity
 
-def _greedy_positions(model, params, adapters, toks):
+def _greedy_positions(model, params, adapters, toks, paged_cache):
     """Per-position logits from the KV-cache decode loop over given tokens."""
     b, s = toks.shape
-    cache = model.init_cache(b, s)
-    step = jax.jit(model.decode_step)
+    cache, table = paged_cache(model, b, s)
+    step = jax.jit(lambda p, c, t, pos, a: model.decode_step(
+        p, c, t, pos, a, table=table))
     outs = []
     for t in range(s):
         logits, cache = step(params, cache, toks[:, t:t + 1],
@@ -149,7 +150,7 @@ def _greedy_positions(model, params, adapters, toks):
 
 
 @pytest.mark.parametrize("tier", ["reference", "interpret"])
-def test_decode_step_matches_forward_with_adapters(tier):
+def test_decode_step_matches_forward_with_adapters(tier, paged_cache):
     """KV-cache decode with an AdapterSet == full forward, position by
     position, on the reference AND interpret kernel tiers."""
     num_layers = 2 if tier == "reference" else 1
@@ -162,7 +163,8 @@ def test_decode_step_matches_forward_with_adapters(tier):
     dispatch.force_mode(tier if tier == "interpret" else None)
     try:
         full, _ = model.forward(params, {"tokens": toks}, adapters=aset)
-        stepped = _greedy_positions(model, params, aset, toks)
+        stepped = _greedy_positions(model, params, aset, toks,
+                                    paged_cache)
     finally:
         dispatch.force_mode(None)
     np.testing.assert_allclose(np.asarray(full), np.asarray(stepped),
@@ -170,7 +172,7 @@ def test_decode_step_matches_forward_with_adapters(tier):
 
 
 @pytest.mark.parametrize("tier", ["reference", "interpret"])
-def test_banked_decode_matches_per_adapter_loop(tier):
+def test_banked_decode_matches_per_adapter_loop(tier, paged_cache):
     """A mixed-rank AdapterBank batch decodes like a python loop over the
     same requests served one adapter at a time."""
     num_layers = 2 if tier == "reference" else 1
@@ -185,10 +187,11 @@ def test_banked_decode_matches_per_adapter_loop(tier):
     ids = jnp.asarray([2, 0, 1])
     dispatch.force_mode(tier if tier == "interpret" else None)
     try:
-        batched = _greedy_positions(model, params, bank.gather(ids), toks)
+        batched = _greedy_positions(model, params, bank.gather(ids), toks,
+                                    paged_cache)
         rows = [
             _greedy_positions(model, params, bank.adapter(int(k)),
-                              toks[i:i + 1])
+                              toks[i:i + 1], paged_cache)
             for i, k in enumerate(ids)]
     finally:
         dispatch.force_mode(None)
@@ -197,7 +200,7 @@ def test_banked_decode_matches_per_adapter_loop(tier):
                                rtol=2e-4, atol=2e-4)
 
 
-def test_bank_k8_mixed_rank_bit_identical_conformance():
+def test_bank_k8_mixed_rank_bit_identical_conformance(paged_cache):
     """Acceptance: a K=8 mixed-rank AdapterBank batched decode is
     bit-identical to K single-adapter decodes.
 
@@ -218,11 +221,12 @@ def test_bank_k8_mixed_rank_bit_identical_conformance():
     K = bank.size
     prompt = jax.random.randint(jax.random.key(5), (K, 2), 0, 64)
 
+    cache0, table = paged_cache(model, K, 8)
     step = jax.jit(lambda cache, tok, pos, ids: model.decode_step(
-        params, cache, tok, pos, adapters=bank.gather(ids)))
+        params, cache, tok, pos, adapters=bank.gather(ids), table=table))
 
     def decode(ids):
-        cache = model.init_cache(K, 8)
+        cache = cache0
         tok = prompt[:, :1]
         seq = [tok]
         for t in range(6):

@@ -98,8 +98,8 @@ def test_r2_flags_jit_in_loop_body():
 
 def test_r2_flags_jit_of_bound_method():
     # model.decode_step is a fresh bound-method object per access: jitting
-    # it inline builds a new executable cache on every call (the PR-5 bug
-    # serve._jit_decode_step exists to prevent)
+    # it inline builds a new executable cache on every call (the bug the
+    # per-model jit cache, serve._model_jit, exists to prevent)
     assert "R2" in _active_rules("""
         import jax
 

@@ -95,14 +95,15 @@ def test_reduced_federated_train_step(arch):
 
 @pytest.mark.parametrize("arch", [a for a in sorted(ASSIGNED)
                                   if supports_shape(a, "decode_32k")])
-def test_reduced_decode_step(arch):
+def test_reduced_decode_step(arch, paged_cache):
     cfg = get_config(arch).reduced()
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
-    cache = model.init_cache(BATCH, SEQ)
+    cache, table = paged_cache(model, BATCH, SEQ)
     tok = jnp.ones((BATCH, 1), jnp.int32)
     logits, cache2 = model.decode_step(params, cache, tok,
-                                       jnp.zeros((BATCH,), jnp.int32))
+                                       jnp.zeros((BATCH,), jnp.int32),
+                                       table=table)
     assert logits.shape == (BATCH, 1, model.vocab_padded)
     assert not bool(jnp.isnan(logits).any())
     assert jax.tree.structure(cache) == jax.tree.structure(cache2)

@@ -157,7 +157,7 @@ def test_bank_k1_equals_single_adapter(tier):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_model_bank_gather_vs_requests_bit_identical():
+def test_model_bank_gather_vs_requests_bit_identical(paged_cache):
     """Through the full model stack, the lazy requests() view decodes
     bit-identically to the materialized gather() path (the reference
     einsums see the same operands in the same contraction order)."""
@@ -178,14 +178,15 @@ def test_model_bank_gather_vs_requests_bit_identical():
     bank = AdapterBank.from_sets(sets)
     ids = jnp.asarray([2, 0], jnp.int32)
     toks = jax.random.randint(jax.random.key(3), (2, 4), 0, 64)
-    step = jax.jit(model.decode_step)
-    lg_g, _ = step(params, model.init_cache(2, 8), toks[:, :1],
+    cache, table = paged_cache(model, 2, 8)
+    step = jax.jit(lambda p, c, t, pos, a: model.decode_step(
+        p, c, t, pos, a, table=table))
+    lg_g, _ = step(params, cache, toks[:, :1],
                    jnp.zeros((2,), jnp.int32), bank.gather(ids))
-    lg_r, _ = step(params, model.init_cache(2, 8), toks[:, :1],
+    lg_r, _ = step(params, cache, toks[:, :1],
                    jnp.zeros((2,), jnp.int32), bank.requests(ids))
     np.testing.assert_array_equal(np.asarray(lg_g), np.asarray(lg_r))
-    pg, _ = model.prefill(params, model.init_cache(2, 8), toks,
-                          bank.gather(ids))
-    pr, _ = model.prefill(params, model.init_cache(2, 8), toks,
-                          bank.requests(ids))
+    pg, _ = model.prefill(params, cache, toks, bank.gather(ids), table=table)
+    pr, _ = model.prefill(params, cache, toks, bank.requests(ids),
+                          table=table)
     np.testing.assert_array_equal(np.asarray(pg), np.asarray(pr))

@@ -282,13 +282,14 @@ def test_eval_perplexity_on_fused_tier():
     assert np.isfinite(ppl) and ppl > 1.0
 
 
-def test_decode_step_routes_through_dispatch():
+def test_decode_step_routes_through_dispatch(paged_cache):
     dispatch.force_mode("interpret")
     model, params, lora = _tiny_setup(_tiny_cfg(True))
-    cache = model.init_cache(2, 16)
+    cache, table = paged_cache(model, 2, 16)
     dispatch.reset_stats()
     logits, _ = model.decode_step(params, cache, jnp.zeros((2, 1), jnp.int32),
                                   jnp.zeros((2,), jnp.int32),
-                                  adapters=AdapterSet(lora=lora, gamma=1.1))
+                                  adapters=AdapterSet(lora=lora, gamma=1.1),
+                                  table=table)
     assert dispatch.stats["fused"] > 0
     assert logits.shape[:2] == (2, 1)

@@ -88,11 +88,20 @@ def test_input_specs_all_shapes(arch):
                 assert tok_s == shape.seq_len
         else:
             spec = model.input_specs(shape)
-            assert spec["token"].shape == (shape.global_batch, 1)
-            assert "cache" in spec
-            # window archs cap the cache at the window size
+            b = shape.global_batch
+            assert spec["token"].shape == (b, 1)
+            # each request owns a virtual ring of seq_len positions in
+            # blocks of 8 — the window's, where the arch caps attention
+            ring = min(shape.seq_len, cfg.attn_window or shape.seq_len)
+            mb = -(-ring // 8)
+            assert spec["table"].shape == (b, mb)
+            assert spec["table"].dtype == jnp.int32
             leaves = jax.tree.leaves(spec["cache"])
             assert leaves, arch
+            pools = [x for p, x in jax.tree_util.tree_flatten_with_path(
+                spec["cache"])[0] if p[-1].key == "k_pool"]
+            for pool in pools:       # (layers,) + (blocks, 8, kh, hd)
+                assert pool.shape[-4:-2] == (b * mb, 8), arch
 
 
 def test_param_spec_rules():
@@ -114,6 +123,28 @@ def test_param_spec_rules():
     assert param_spec(("stack", "tail", "t0", "mlp", "w_down"),
                       (14336, 5120), m) == P("model", None)
     assert param_spec(("final_scale",), (5120,), m) == P(None)
+
+
+def test_cache_spec_pool_rules():
+    """The KV pool's block dim shards over the batch axes where it divides
+    (like a batch dim); the kv-head dim, else the head dim, over model."""
+    from repro.sharding.rules import cache_spec
+
+    class FakeMesh:
+        axis_names = ("data", "model")
+        shape = {"data": 16, "model": 16}
+    m = FakeMesh()
+    rep = ("repeat", "p0")
+    assert cache_spec(rep + ("k_pool",), (40, 4096, 8, 16, 128), m) == P(
+        None, "data", None, "model", None)
+    # 8 kv heads do not divide 16: the head dim takes model
+    assert cache_spec(rep + ("v_pool",), (40, 4096, 8, 8, 128), m) == P(
+        None, "data", None, None, "model")
+    assert cache_spec(rep + ("pos_pool",), (40, 4096, 8), m) == P(
+        None, "data", None)
+    # a block count the batch axes do not divide stays replicated
+    assert cache_spec(("tail", "t0", "self", "k_pool"), (1025, 8, 16, 64),
+                      m) == P(None, None, "model", None)
 
 
 @pytest.mark.parametrize("env,want_repo", [({}, True),

@@ -7,8 +7,8 @@ Four layers of guarantees, strongest first:
     rank-ceiling / structure violations instead of silently reshaping.
   * ZERO RECOMPILES: what the serving engines trace (``bank.requests``)
     keeps its treedef and leaf shapes across publishes, and the jitted
-    engine caches (fixed generate, paged admit/chunk, the slot-swap
-    executable itself) do not grow when publishes land mid-serve.
+    engine caches (paged admit/chunk, the slot-swap executable itself) do
+    not grow when publishes land between or during serves.
   * ``LiveAdapterBank`` residency: LRU promotion into free-then-oldest
     slots, pinned slots never evicted (impossible acquires defer, not
     corrupt), demotion is free because the host store is authoritative,
@@ -132,26 +132,36 @@ def test_bank_version_is_not_a_cache_key(tiny):
     assert jax.tree.unflatten(td, leaves).version == 0
 
 
-def test_publish_zero_recompile_fixed_engine(tiny):
-    """Publishing between generate_banked calls reuses every executable:
-    neither the generation program nor the slot-swap jit gains an entry."""
+def test_publish_zero_recompile_between_serves(tiny):
+    """Publishing between serve_scheduled runs reuses every executable:
+    neither engine program (paged admit/chunk) nor the slot-swap jit gains
+    an entry."""
     cfg, model, params = tiny
     bank = _mk_bank(params, cfg)
-    ids = jnp.asarray([0, 1, 2])
-    prompt = jnp.asarray(np.full((3, 4), 7), jnp.int32)
-    out0 = serve.generate_banked(model, params, bank, ids, prompt, 4, 8)
+    prompt = np.full(4, 7, np.int32)
+
+    def run(bank):
+        reqs = [serve.Request(rid=i, prompt=prompt, steps=4, adapter_id=i)
+                for i in range(3)]
+        done = serve.serve_scheduled(model, params, reqs, bank=bank,
+                                     max_batch=3, chunk=3, wait=False)
+        return [r.tokens for r in done]
+
+    out0 = run(bank)
     bank = bank.publish(0, _mk_set(params, cfg, 4, seed=19))  # warm the swap
-    gen_c = model._serve_jit_cache["generate"]._cache_size()
+    engines = [model._serve_jit_cache[k] for k in ("paged_admit",
+                                                   "paged_chunk")]
+    sizes = [f._cache_size() for f in engines]
     swap_c = _bank_slot_swap._cache_size()
     for slot in (0, 1, 2):
         bank = bank.publish(slot, _mk_set(params, cfg, 4, seed=20 + slot))
-        serve.generate_banked(model, params, bank, ids, prompt, 4, 8)
-    assert model._serve_jit_cache["generate"]._cache_size() == gen_c
+        run(bank)
+    assert [f._cache_size() for f in engines] == sizes
     assert _bank_slot_swap._cache_size() == swap_c
     assert bank.version == 4
-    # and the published adapters actually serve: tenant rows changed
-    out3 = serve.generate_banked(model, params, bank, ids, prompt, 4, 8)
-    assert out0.shape == out3.shape
+    # and the published adapters actually serve: same shapes out
+    out3 = run(bank)
+    assert [len(t) for t in out0] == [len(t) for t in out3]
 
 
 # ------------------------------------------------------- live bank residency
@@ -370,10 +380,11 @@ def test_out_of_range_adapter_id_raises(tiny):
     would silently clamp to the last tenant) — naming the offending rid."""
     cfg, model, params = tiny
     bank = _mk_bank(params, cfg)
-    prompt = jnp.zeros((2, 4), jnp.int32)
+    reqs = [serve.Request(rid=i, prompt=np.zeros(4, np.int32), steps=2,
+                          adapter_id=k) for i, k in enumerate((0, 3))]
     with pytest.raises(ValueError, match="clamp"):
-        serve.generate_banked(model, params, bank, jnp.asarray([0, 3]),
-                              prompt, 2, 8)
+        serve.serve_scheduled(model, params, reqs, bank=bank, max_batch=2,
+                              wait=False)
     reqs = [serve.Request(rid=5, prompt=np.zeros(4, np.int32), steps=2,
                           adapter_id=-1)]
     with pytest.raises(ValueError, match="rid=5"):

@@ -6,13 +6,14 @@ Four layers of guarantees, strongest first:
     installed, a seeded op-sequence sweep otherwise): no block aliasing
     across outstanding allocations, the null block 0 is never handed out,
     frees return capacity exactly, double frees raise without corrupting.
-  * Paged fill/gather reproduces the ring-buffer layout ELEMENT FOR
-    ELEMENT — including sliding-window ring overflow (prompt longer than
-    the ring) — whenever block_size divides the ring size.
+  * Paged fill puts every token's K/V and position at its virtual-ring
+    slot ``pool[table[i, (p % vlen) // bs], (p % vlen) % bs]`` —
+    including sliding-window ring overflow (prompt longer than the ring,
+    only the last vlen positions survive) — and writes nothing else.
   * The Pallas paged-attention kernel matches the exact-softmax oracle
     (kernels/ref.py) to fp32 tolerance across window/softcap variants.
-  * The scheduled paged engine is token-IDENTICAL to the PR-5 fixed-batch
-    engine at a static schedule, on the reference tier and under the
+  * The scheduler serves greedy tokens of ``Model.forward`` (the plain
+    reference) at a static schedule, on the reference tier and under the
     Pallas interpreter (BGMV adapter kernels engaged), through slot/block
     churn (waves recycling freed slots and blocks), and for per-slot
     recurrent state (rglru blocks reset at admission).
@@ -107,7 +108,7 @@ else:
             _check_pool_ops(num_blocks, ops)
 
 
-def test_block_pool_rejects_degenerate():
+def test_block_pool_rejects_degenerate_size():
     with pytest.raises(ValueError):
         serve.BlockPool(1)        # no room for the null block + any request
 
@@ -142,52 +143,54 @@ def test_block_pool_duplicate_in_one_call_raises():
     pool.free([b])                      # the block is still cleanly held
 
 
-# ------------------------------------------------- ring vs paged layout parity
+# ------------------------------------------------- paged fill layout
 
-def _check_ring_paged_layout(seed, batch, size, bs, s):
-    """Random prompt fill + sequential decode writes: the paged gather must
-    reproduce the ring arrays element for element (bs divides size)."""
+def _check_paged_fill_layout(seed, batch, mb, bs, s):
+    """Random prompt fill of ``s`` positions into rings of ``mb * bs``
+    slots: each surviving token (the last vlen positions) sits at its
+    virtual-ring slot, and every other slot — the null block's too —
+    stays unwritten."""
     cfg = _cfg()
-    mb = size // bs
-    key = jax.random.key(seed)
-    kk, kv = jax.random.split(key)
+    vlen = mb * bs
+    kk, kv = jax.random.split(jax.random.key(seed))
     k = jax.random.normal(kk, (batch, s, cfg.num_kv_heads, cfg.head_dim))
     v = jax.random.normal(kv, (batch, s, cfg.num_kv_heads, cfg.head_dim))
     positions = jnp.broadcast_to(jnp.arange(s)[None], (batch, s))
-
-    ring = attention.init_kv_cache(cfg, batch, size, k.dtype)
-    ring = attention.fill_kv_cache(ring, k, v, positions)
-
     paged = attention.init_paged_kv_cache(cfg, 1 + batch * mb, bs, k.dtype)
     table = jnp.arange(1, 1 + batch * mb, dtype=jnp.int32).reshape(batch, mb)
     paged = attention.fill_paged_kv_cache(paged, k, v, positions, table)
-
-    kg, vg, pg = attention.paged_gather(paged, table)
-    np.testing.assert_array_equal(np.asarray(ring["k"]), np.asarray(kg))
-    np.testing.assert_array_equal(np.asarray(ring["v"]), np.asarray(vg))
-    np.testing.assert_array_equal(np.asarray(ring["pos"]), np.asarray(pg))
-    assert not np.any(np.asarray(paged["pos_pool"][0]) >= 0), \
-        "fill leaked into the null block"
+    kp, vp, pp = (np.asarray(paged[n])
+                  for n in ("k_pool", "v_pool", "pos_pool"))
+    k, v, tab = np.asarray(k), np.asarray(v), np.asarray(table)
+    written = np.zeros(pp.shape, bool)
+    for i in range(batch):
+        for p in range(max(0, s - vlen), s):
+            blk, off = tab[i, (p % vlen) // bs], (p % vlen) % bs
+            np.testing.assert_array_equal(kp[blk, off], k[i, p])
+            np.testing.assert_array_equal(vp[blk, off], v[i, p])
+            assert pp[blk, off] == p
+            written[blk, off] = True
+    assert (pp[~written] == -1).all(), "fill wrote outside the survivors"
 
 
 if HAVE_HYPOTHESIS:
     @settings(max_examples=40, deadline=None)
     @given(seed=st.integers(0, 2**31 - 1), batch=st.integers(1, 3),
            mb=st.integers(1, 4), bs=st.sampled_from([1, 2, 4]),
-           extra=st.integers(0, 12))
-    def test_ring_vs_paged_fill_layout(seed, batch, mb, bs, extra):
+           extra=st.integers(-3, 12))
+    def test_paged_fill_layout(seed, batch, mb, bs, extra):
         # extra > 0 overflows the ring (sliding-window prompt longer than
-        # the cache) — the survivors must still agree
-        _check_ring_paged_layout(seed, batch, mb * bs, bs, mb * bs + extra)
+        # the ring) — only the survivors land; extra < 0 leaves slots empty
+        _check_paged_fill_layout(seed, batch, mb, bs, max(1, mb * bs + extra))
 else:
-    def test_ring_vs_paged_fill_layout():
+    def test_paged_fill_layout():
         rng = random.Random(1)
         for _ in range(40):
             bs = rng.choice([1, 2, 4])
             mb = rng.randint(1, 4)
-            _check_ring_paged_layout(rng.randint(0, 2**31 - 1),
-                                     rng.randint(1, 3), mb * bs, bs,
-                                     mb * bs + rng.randint(0, 12))
+            _check_paged_fill_layout(rng.randint(0, 2**31 - 1),
+                                     rng.randint(1, 3), mb, bs,
+                                     max(1, mb * bs + rng.randint(-3, 12)))
 
 
 # ------------------------------------------------- Pallas kernel vs exact oracle
@@ -219,7 +222,7 @@ def test_paged_attention_kernel_matches_oracle(window, softcap):
                                rtol=2e-5, atol=2e-5)
 
 
-# ------------------------------------------------- scheduled vs fixed identity
+# ------------------------------------------------- scheduled vs forward
 
 def _bank(model, params, ranks=(4, 8)):
     cfg = model.cfg
@@ -231,72 +234,68 @@ def _bank(model, params, ranks=(4, 8)):
     return AdapterBank.from_sets(sets)
 
 
-def _run_static_identity(cfg, *, bank_ranks=None, B=4, p=8, steps=12,
-                         block_size=4, chunk=5, max_len=None):
-    """All-at-once arrivals, uniform shapes: scheduled greedy tokens must
-    equal the fixed-batch engine's exactly."""
+def _run_static_vs_forward(check, cfg, *, bank_ranks=None, B=4, p=8,
+                           steps=12, block_size=4, chunk=5, max_len=None):
+    """All-at-once arrivals, uniform shapes: every request's scheduled
+    greedy tokens are the forward's over its prompt and tokens."""
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
     bank = _bank(model, params, bank_ranks) if bank_ranks else None
     prompt = np.asarray(jax.random.randint(jax.random.key(2), (B, p), 0,
                                            cfg.vocab_size), np.int32)
-    max_len = max_len or p + steps
     ids = np.arange(B, dtype=np.int32) % (bank.size if bank else 1)
-    if bank is not None:
-        fixed = serve.generate_banked(model, params, bank, jnp.asarray(ids),
-                                      jnp.asarray(prompt), steps, max_len)
-    else:
-        fixed = serve.generate(model, params, jnp.asarray(prompt), steps,
-                               max_len)
-    fixed = np.asarray(fixed)[:, p:]
     reqs = [serve.Request(rid=i, prompt=prompt[i], steps=steps,
                           adapter_id=int(ids[i])) for i in range(B)]
     done = serve.serve_scheduled(model, params, reqs, bank=bank, max_batch=B,
                                  block_size=block_size, chunk=chunk,
-                                 max_len=max_len, wait=False)
-    sched = np.stack([np.asarray(r.tokens) for r in done])
-    np.testing.assert_array_equal(fixed, sched)
+                                 max_len=max_len or p + steps, wait=False)
+    for r in done:
+        assert len(r.tokens) == steps
+        check(model, params, r.prompt, r.tokens,
+              bank.adapter(r.adapter_id) if bank else None)
     return model
 
 
-def test_scheduled_identity_base():
-    _run_static_identity(_cfg())
+def test_scheduled_matches_forward_base(greedy_vs_forward):
+    _run_static_vs_forward(greedy_vs_forward, _cfg())
 
 
-def test_scheduled_identity_banked():
-    _run_static_identity(_cfg(), bank_ranks=(4, 8))
+def test_scheduled_matches_forward_banked(greedy_vs_forward):
+    _run_static_vs_forward(greedy_vs_forward, _cfg(), bank_ranks=(4, 8))
 
 
-def test_scheduled_identity_sliding_window_overflow():
-    # max_len 8 < prompt+steps 17: both engines wrap their (virtual) ring;
-    # block_size 4 divides 8 so the layouts stay element-identical
-    _run_static_identity(_cfg(attn_window=6), p=5, steps=12, max_len=8,
-                         block_size=2)
+def test_scheduled_matches_forward_sliding_window_overflow(
+        greedy_vs_forward):
+    # max_len 8 < prompt+steps 17: each request wraps its window-sized
+    # virtual ring (6 slots in blocks of 2); the forward's windowed
+    # attention is the reference
+    _run_static_vs_forward(greedy_vs_forward, _cfg(attn_window=6), p=5,
+                           steps=12, max_len=8, block_size=2)
 
 
-def test_scheduled_identity_recurrent_blocks():
+def test_scheduled_matches_forward_recurrent_blocks(greedy_vs_forward):
     # per-slot recurrent state (rglru h/conv tail) must come back fresh at
     # admission and merge without disturbing attention pools
-    _run_static_identity(_cfg(num_layers=4,
-                              block_pattern=("rglru", "attn")),
-                         B=2, p=6, steps=8)
+    _run_static_vs_forward(greedy_vs_forward,
+                           _cfg(num_layers=4, block_pattern=("rglru", "attn")),
+                           B=2, p=6, steps=8)
 
 
-def test_scheduled_identity_interpret_tier():
+def test_scheduled_matches_forward_interpret_tier(greedy_vs_forward):
     # the full serving stack under the Pallas interpreter: BGMV adapter
-    # kernel bodies run inside both engines; tokens still identical
+    # kernel bodies run inside both engine programs
     dispatch.force_mode("interpret")
     dispatch.reset_stats()
-    _run_static_identity(_cfg(use_pallas=True), bank_ranks=(4, 8), B=2,
-                         p=5, steps=6, chunk=3)
+    _run_static_vs_forward(greedy_vs_forward, _cfg(use_pallas=True),
+                           bank_ranks=(4, 8), B=2, p=5, steps=6, chunk=3)
     assert dispatch.stats["bgmv"] > 0, "BGMV kernel tier never engaged"
 
 
-def test_scheduled_churn_matches_fixed_waves():
+def test_scheduled_churn_matches_forward(greedy_vs_forward):
     """Staggered completion: 6 requests through 2 engine slots — three
-    waves recycling freed slots AND freed blocks.  Each wave must match
-    the fixed engine run on that wave alone (same shapes), proving freed
-    blocks are reset before reuse and per-slot merge doesn't leak."""
+    waves recycling freed slots AND freed blocks.  Every request still
+    serves the forward's greedy tokens, proving freed blocks are reset
+    before reuse and per-slot merge doesn't leak."""
     cfg = _cfg()
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
@@ -305,18 +304,17 @@ def test_scheduled_churn_matches_fixed_waves():
     prompt = np.asarray(jax.random.randint(jax.random.key(3), (N, p), 0,
                                            cfg.vocab_size), np.int32)
     ids = np.asarray([0, 1, 1, 0, 0, 1], np.int32)
-    fixed = np.concatenate([
-        np.asarray(serve.generate_banked(
-            model, params, bank, jnp.asarray(ids[w:w + 2]),
-            jnp.asarray(prompt[w:w + 2]), steps, max_len))
-        for w in range(0, N, 2)])[:, p:]
     reqs = [serve.Request(rid=i, prompt=prompt[i], steps=steps,
                           adapter_id=int(ids[i])) for i in range(N)]
-    done = serve.serve_scheduled(model, params, reqs, bank=bank, max_batch=2,
-                                 block_size=4, chunk=4, max_len=max_len,
-                                 wait=False)
-    sched = np.stack([np.asarray(r.tokens) for r in done])
-    np.testing.assert_array_equal(fixed, sched)
+    with trace.tracing() as t:
+        done = serve.serve_scheduled(model, params, reqs, bank=bank,
+                                     max_batch=2, block_size=4, chunk=4,
+                                     max_len=max_len, wait=False)
+    assert t.counters["serve.admitted"] == N
+    for r in done:
+        assert len(r.tokens) == steps
+        greedy_vs_forward(model, params, r.prompt, r.tokens,
+                          bank.adapter(r.adapter_id))
 
 
 def test_scheduled_mixed_lengths_and_steps_complete():
@@ -353,7 +351,7 @@ def test_scheduled_immediate_finish_latency_sane():
     """steps=1 requests finish AT admission (their only token comes from
     the prefill); under wait=True their t_done is taken from t_first, so
     both timestamps must exist, be monotone w.r.t. arrival, and yield
-    non-negative latency — the metrics serve_bench aggregates."""
+    non-negative latency — the latency metrics aggregate them."""
     cfg = _cfg()
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
@@ -371,10 +369,11 @@ def test_scheduled_immediate_finish_latency_sane():
         assert r.t_done == r.t_first
 
 
-def test_scheduled_block_starvation_waits_not_fails():
+def test_scheduled_block_starvation_waits_not_fails(greedy_vs_forward):
     """With exactly one request's worth of blocks, admission serializes:
-    every request still completes (the head of the queue waits for blocks
-    instead of deadlocking or aliasing)."""
+    every request still completes with the forward's greedy tokens (the
+    head of the queue waits for blocks instead of deadlocking or
+    aliasing)."""
     cfg = _cfg()
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
@@ -386,11 +385,8 @@ def test_scheduled_block_starvation_waits_not_fails():
                                  block_size=4, chunk=2, max_len=12,
                                  wait=False)
     assert all(len(r.tokens) == 5 for r in done)
-    fixed = np.concatenate([
-        np.asarray(serve.generate(model, params, jnp.asarray(prompt[i:i+1]),
-                                  5, 12))[:, 4:] for i in range(3)])
-    np.testing.assert_array_equal(fixed,
-                                  np.stack([r.tokens for r in done]))
+    for r in done:
+        greedy_vs_forward(model, params, r.prompt, r.tokens)
 
 
 # ------------------------------------------------- deadline-bounded serving
