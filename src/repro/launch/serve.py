@@ -281,7 +281,10 @@ def _jit_paged_chunk(model):
     """Jitted decode chunk: ``steps`` greedy tokens for every engine slot
     in one lax.scan.  ``active`` gates token emission and position
     advance; inactive slots still run (static shapes) but write into the
-    null block and their outputs are discarded host-side."""
+    null block and their outputs are discarded host-side.  The chunk
+    donates ``cache``: the pool is written in place from the call's input
+    to its output, so a caller rebinds it from the result and never reads
+    the one it passed."""
     def build(m):
         def chunk_run(params, cache, tok, pos, active, table, adapters, *,
                       steps):
@@ -301,7 +304,8 @@ def _jit_paged_chunk(model):
             (cache, tok, pos), toks = jax.lax.scan(
                 step, (cache, tok, pos), None, length=steps)
             return cache, tok, pos, toks.T
-        return jax.jit(chunk_run, static_argnames=("steps",))
+        return jax.jit(chunk_run, static_argnames=("steps",),
+                       donate_argnums=(1,))
     return _model_jit(model, "paged_chunk", build)
 
 
@@ -348,9 +352,10 @@ def serve_scheduled(model, params, requests, *, bank=None, max_batch=4,
     ``serve.queued`` span; the counters are ``serve.dispatches``,
     ``serve.admitted``, ``serve.timeouts``, ``serve.decode_tokens`` (tokens
     kept from chunks) and ``serve.decode_slot_steps`` (slots x steps
-    run).  Before the loop, :func:`serving_base` makes the frozen base's
-    view for the run (span ``serve.prepare``, counter
-    ``serve.base_bf16_leaves``)."""
+    run), and, once per trace of the decode chunk,
+    ``serve.kv_inplace_layers`` (``transformer.decode_stack``).  Before the
+    loop, :func:`serving_base` makes the frozen base's view for the run
+    (span ``serve.prepare``, counter ``serve.base_bf16_leaves``)."""
     reqs = sorted(requests, key=lambda r: (r.arrival, r.rid))
     if not reqs:
         return []

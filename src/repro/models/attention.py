@@ -237,15 +237,18 @@ def init_paged_kv_cache(cfg, num_blocks: int, block_size: int, dtype):
     }
 
 
-def paged_gather(cache, table):
+def paged_gather(cache, table, layer=None):
     """Materialize each request's virtual ring view of the pool:
-    (k (b, vlen, kh, hd), v (b, vlen, kh, hd), pos (b, vlen))."""
+    (k (b, vlen, kh, hd), v (b, vlen, kh, hd), pos (b, vlen)).  With
+    ``layer`` the pools are stacked over layers and the view is of that
+    layer's, gathered straight from the stack."""
     b, mb = table.shape
-    bs = cache["k_pool"].shape[1]
-    kh, hd = cache["k_pool"].shape[2:]
-    k = cache["k_pool"][table].reshape(b, mb * bs, kh, hd)
-    v = cache["v_pool"][table].reshape(b, mb * bs, kh, hd)
-    pos = cache["pos_pool"][table].reshape(b, mb * bs)
+    lead = () if layer is None else (layer,)
+    bs = cache["k_pool"].shape[len(lead) + 1]
+    kh, hd = cache["k_pool"].shape[len(lead) + 2:]
+    k = cache["k_pool"][lead + (table,)].reshape(b, mb * bs, kh, hd)
+    v = cache["v_pool"][lead + (table,)].reshape(b, mb * bs, kh, hd)
+    pos = cache["pos_pool"][lead + (table,)].reshape(b, mb * bs)
     return k, v, pos
 
 
@@ -290,9 +293,13 @@ def attention_prefill_paged(cfg, params, x, cache, positions, table, *,
 
 
 def attention_decode_paged(cfg, params, x, cache, table, pos, *,
-                           adapters=None):
+                           layer=None, adapters=None):
     """One-token decode against the block pool.  x (b,1,d); table (b,
-    blocks_per_req) int32; pos (b,) absolute position.
+    blocks_per_req) int32; pos (b,) absolute position.  With ``layer`` the
+    pools are stacked over the scanned layers (a leading layer dim): the
+    step writes its rows at ``[layer, block, offset]`` and reads that
+    layer's ring from the same buffer, which the layer scan then carries
+    on in place.
 
     Reference tier gathers each request's blocks into its virtual ring
     (:func:`paged_gather`) and masks by the gathered positions; on the
@@ -301,7 +308,8 @@ def attention_decode_paged(cfg, params, x, cache, table, pos, *,
     from repro.kernels import dispatch
 
     b = x.shape[0]
-    bs = cache["k_pool"].shape[1]
+    lead = () if layer is None else (layer,)
+    bs = cache["k_pool"].shape[len(lead) + 1]
     vlen = table.shape[1] * bs
     q, k, v = _project_qkv(cfg, params, x, adapters=adapters,
                            positions=pos[:, None], kv_positions=pos[:, None])
@@ -309,18 +317,22 @@ def attention_decode_paged(cfg, params, x, cache, table, pos, *,
     bidx = jnp.arange(b)
     blk = table[bidx, vslot // bs]
     off = vslot % bs
-    new_cache = {"k_pool": cache["k_pool"].at[blk, off].set(k[:, 0]),
-                 "v_pool": cache["v_pool"].at[blk, off].set(v[:, 0]),
-                 "pos_pool": cache["pos_pool"].at[blk, off].set(pos)}
+    at = lead + (blk, off)
+    new_cache = {"k_pool": cache["k_pool"].at[at].set(k[:, 0]),
+                 "v_pool": cache["v_pool"].at[at].set(v[:, 0]),
+                 "pos_pool": cache["pos_pool"].at[at].set(pos)}
     if dispatch.resolve_mode() == "pallas":
         from repro.kernels.paged_attention import paged_attention
         dispatch.stats["paged"] += 1
+        # the kernel takes one layer's pools: a copy of it on this tier
+        pools = (new_cache if layer is None
+                 else {n: a[layer] for n, a in new_cache.items()})
         out = paged_attention(
-            q[:, 0], new_cache["k_pool"], new_cache["v_pool"],
-            new_cache["pos_pool"], table, pos,
+            q[:, 0], pools["k_pool"], pools["v_pool"],
+            pools["pos_pool"], table, pos,
             window=cfg.attn_window, softcap=cfg.attn_logit_softcap)[:, None]
     else:
-        kg, vg, pg = paged_gather(new_cache, table)
+        kg, vg, pg = paged_gather(new_cache, table, layer)
         mask = make_mask(pos[:, None], pg, causal=True,
                          window=cfg.attn_window, valid_kv=pg >= 0)
         out = attention_core(cfg, q, kg, vg, mask)

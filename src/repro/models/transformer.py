@@ -9,7 +9,8 @@ unrolled.  Every block kind supports:
   apply_block(..., mode="fullseq")                       -> (x, aux)
   init_paged_block_cache(cfg, kind, num_blocks,
                          block_size, batch, dtype)       -> cache pytree
-  apply_block(..., mode="decode", cache=, pos=, table=)  -> (x, aux, cache)
+  apply_block(..., mode="decode", cache=, pos=, table=,
+              layer=)                                    -> (x, aux, cache)
 
 Kinds: "attn" (GQA attention + MLP/MoE), "xattn" (self+cross attention + MLP,
 for encoder-decoder), "rglru" (RG-LRU temporal mix + MLP), "mlstm", "slstm".
@@ -21,6 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.analysis import trace
 from repro.models import scan_unroll
 from repro.models import attention as attn_mod
 from repro.models import rglru as rglru_mod
@@ -117,9 +119,14 @@ def build_cross_kv(cfg, p_cross, enc_out):
     return k, v
 
 
+def _at_layer(tree, layer):
+    """One layer of a stacked cache subtree (all of it without a layer)."""
+    return tree if layer is None else jax.tree.map(lambda a: a[layer], tree)
+
+
 def apply_block(cfg, kind, p, x, *, adapters=None, positions=None,
                 causal=True, mode="fullseq", cache=None, pos=None,
-                enc_out=None, table=None):
+                enc_out=None, table=None, layer=None):
     """``mode``: "fullseq" (train/encode — no cache), "prefill" (whole
     prompt in one pass, cache filled as the token-by-token decode would
     have), "decode" (one token against the cache).  Prefill and decode
@@ -127,11 +134,21 @@ def apply_block(cfg, kind, p, x, *, adapters=None, positions=None,
 
     Attention K/V live in the block pool (models/attention.py), addressed
     through ``table`` (b, blocks_per_req) int32; prefill and decode of an
-    attention block need it.  Non-attention state is per-slot."""
+    attention block need it.  Non-attention state is per-slot.
+
+    A scanned block's decode gets its index ``layer``: ``cache`` then holds
+    every scanned layer of this block's state (a leading layer dim), and
+    the block reads and writes its own layer in it, so the whole stacked
+    cache stays one buffer through the layer scan."""
     adapters = adapters or {}
     aux = jnp.zeros((), jnp.float32)
     h1 = apply_norm(cfg, x, p, "ln1")
     new_cache = None
+    # a recurrent block's decode steps its layer's per-slot state; the
+    # attention pools are read and written in place by
+    # attention_decode_paged
+    per_slot = mode == "decode" and kind not in ("attn", "xattn")
+    state = _at_layer(cache, layer) if per_slot else cache
 
     if kind in ("attn", "xattn"):
         self_cache = (None if cache is None
@@ -146,7 +163,7 @@ def apply_block(cfg, kind, p, x, *, adapters=None, positions=None,
                 adapters=adapters.get("attn"))
         else:
             a, self_cache = attention_decode_paged(
-                cfg, p["attn"], h1, self_cache, table, pos,
+                cfg, p["attn"], h1, self_cache, table, pos, layer=layer,
                 adapters=adapters.get("attn"))
         x = x + a
         if kind == "xattn":
@@ -158,7 +175,9 @@ def apply_block(cfg, kind, p, x, *, adapters=None, positions=None,
                 ck, cv = cache["cross_k"], cache["cross_v"]
             else:
                 ck, cv = build_cross_kv(cfg, p["cross"], enc_out)
-            x = x + _cross_attention(cfg, p["cross"], hx, ck, cv,
+            x = x + _cross_attention(cfg, p["cross"], hx,
+                                     _at_layer(ck, layer),
+                                     _at_layer(cv, layer),
                                      adapters=adapters.get("cross"))
         h2 = apply_norm(cfg, x, p, "ln2")
         if cfg.moe is not None:
@@ -181,7 +200,7 @@ def apply_block(cfg, kind, p, x, *, adapters=None, positions=None,
                 cfg, p["rglru"], h1, cache, positions, adapters.get("rglru"))
         else:
             r, new_cache = rglru_mod.rglru_apply_decode(
-                cfg, p["rglru"], h1, cache, pos, adapters.get("rglru"))
+                cfg, p["rglru"], h1, state, pos, adapters.get("rglru"))
         x = x + r
         h2 = apply_norm(cfg, x, p, "ln2")
         x = x + mlp_apply(cfg, p["mlp"], h2)
@@ -195,7 +214,7 @@ def apply_block(cfg, kind, p, x, *, adapters=None, positions=None,
                 cfg, p["mlstm"], h1, cache, positions, adapters.get("mlstm"))
         else:
             m, new_cache = xlstm_mod.mlstm_apply_decode(
-                cfg, p["mlstm"], h1, cache, pos, adapters.get("mlstm"))
+                cfg, p["mlstm"], h1, state, pos, adapters.get("mlstm"))
         x = x + m
 
     elif kind == "slstm":
@@ -207,13 +226,16 @@ def apply_block(cfg, kind, p, x, *, adapters=None, positions=None,
                 cfg, p["slstm"], h1, cache, positions, adapters.get("slstm"))
         else:
             s_, new_cache = xlstm_mod.slstm_apply_decode(
-                cfg, p["slstm"], h1, cache, pos, adapters.get("slstm"))
+                cfg, p["slstm"], h1, state, pos, adapters.get("slstm"))
         x = x + s_
     else:
         raise ValueError(kind)
 
     if mode == "fullseq":
         return x, aux
+    if per_slot and layer is not None:
+        new_cache = jax.tree.map(lambda a, n: a.at[layer].set(n), cache,
+                                 new_cache)
     return x, aux, new_cache
 
 
@@ -494,29 +516,39 @@ def prefill_stack(cfg, stack_params, cache, x, positions, *, adapters=None,
 
 def decode_stack(cfg, stack_params, cache, x, pos, *, adapters=None,
                  pattern=None, table=None):
-    """One-token decode through the stack.  Returns (x, new_cache)."""
+    """One-token decode through the stack.  Returns (x, new_cache).
+
+    The layer scan carries the whole stacked ``cache["repeat"]`` and scans
+    each layer's index beside its params: every block writes its step into
+    its own layer of the one buffer (the attention pools at ``[layer,
+    block, offset]``) and reads its layer back from it, so no step builds
+    another pool.  Recorded once per trace as the counter
+    ``serve.kv_inplace_layers``: the scanned attention layers whose pool
+    the step writes in place."""
     pattern = pattern or cfg.block_pattern
     adapters = adapters or {}
     rep_p = stack_params.get("repeat", {})
     rep_lora = adapters.get("repeat") or _empty_like_stack(rep_p)
 
-    def scan_body(h, xs):
-        ps, los, cs = xs
-        new_cs = {}
+    def scan_body(carry, xs):
+        h, cs = carry
+        i, ps, los = xs
+        cs = dict(cs)
         for j, kind in enumerate(pattern):
-            h, _, nc = apply_block(cfg, kind, ps[f"p{j}"], h,
-                                   adapters=los.get(f"p{j}"),
-                                   mode="decode", cache=cs[f"p{j}"], pos=pos,
-                                   table=table)
-            new_cs[f"p{j}"] = nc
-        return h, new_cs
+            h, _, cs[f"p{j}"] = apply_block(
+                cfg, kind, ps[f"p{j}"], h, adapters=los.get(f"p{j}"),
+                mode="decode", cache=cs[f"p{j}"], pos=pos, table=table,
+                layer=i)
+        return (h, cs), None
 
     new_cache = {"repeat": {}, "tail": {}}
     if rep_p:
         n_rep = jax.tree.leaves(rep_p)[0].shape[0]
-        x, new_cache["repeat"] = jax.lax.scan(
-            scan_body, x, (rep_p, rep_lora, cache["repeat"]),
-            unroll=scan_unroll(n_rep))
+        (x, new_cache["repeat"]), _ = jax.lax.scan(
+            scan_body, (x, cache["repeat"]),
+            (jnp.arange(n_rep), rep_p, rep_lora), unroll=scan_unroll(n_rep))
+        trace.count("serve.kv_inplace_layers",
+                    n_rep * sum(k in ("attn", "xattn") for k in pattern))
     kinds = _tail_kinds(cfg, pattern, stack_params)
     for i, kind in enumerate(kinds):
         key = f"t{i}"

@@ -66,16 +66,18 @@ def paged_cache():
 
 @pytest.fixture
 def greedy_vs_forward():
-    """``check(model, params, prompt, tokens, adapters=None, tol=1e-4)``:
-    served tokens against the plain reference, ``Model.forward`` on the
-    reference kernel tier over the prompt followed by the served tokens.
+    """``check(model, params, prompt, tokens, adapters=None, tol=1e-4,
+    frames=None)``: served tokens against the plain reference,
+    ``Model.forward`` on the reference kernel tier over the prompt followed
+    by the served tokens (and an encoder-decoder model's ``frames``).
     Every token must be a real-vocabulary id whose logit at the previous
     position lies within ``tol`` of that position's maximum over the real
     vocabulary: a greedy choice, up to near-ties.  Returns the largest
     gap."""
     from repro.models.api import build_model
 
-    def check(model, params, prompt, tokens, adapters=None, tol=1e-4):
+    def check(model, params, prompt, tokens, adapters=None, tol=1e-4,
+              frames=None):
         vocab = model.cfg.vocab_size
         prompt = np.asarray(prompt, np.int32)
         tokens = np.asarray(tokens, np.int32)
@@ -83,8 +85,10 @@ def greedy_vs_forward():
             f"token outside the real vocabulary {vocab}: {tokens}"
         ref = build_model(dataclasses.replace(model.cfg, use_pallas=False))
         seq = np.concatenate([prompt, tokens])[None]
-        logits, _ = ref.forward(params, {"tokens": jnp.asarray(seq)},
-                                adapters=adapters)
+        batch = {"tokens": jnp.asarray(seq)}
+        if frames is not None:
+            batch["frames"] = frames
+        logits, _ = ref.forward(params, batch, adapters=adapters)
         lg = np.asarray(logits[0, len(prompt) - 1:-1, :vocab], np.float32)
         gap = lg.max(-1) - lg[np.arange(len(tokens)), tokens]
         assert gap.max() <= tol, (

@@ -14,11 +14,16 @@ Four layers of guarantees, strongest first:
     (kernels/ref.py) to fp32 tolerance across window/softcap variants.
   * The scheduler serves greedy tokens of ``Model.forward`` (the plain
     reference) at a static schedule, on the reference tier and under the
-    Pallas interpreter (BGMV adapter kernels engaged), through slot/block
-    churn (waves recycling freed slots and blocks), and for per-slot
-    recurrent state (rglru blocks reset at admission).
+    Pallas interpreter (BGMV adapter kernels engaged, and the paged
+    attention kernel on the stacked pools), through slot/block churn
+    (waves recycling freed slots and blocks into a donated pool), and for
+    per-slot recurrent state (rglru blocks reset at admission).
+  * The compiled decode chunk writes the pool in place: no pool-shaped
+    copy, fill or dynamic-update-slice, and the pool aliased from the
+    call's input to its output.
 """
 import random
+import re
 
 import jax
 import jax.numpy as jnp
@@ -291,6 +296,23 @@ def test_scheduled_matches_forward_interpret_tier(greedy_vs_forward):
     assert dispatch.stats["bgmv"] > 0, "BGMV kernel tier never engaged"
 
 
+def test_scheduled_pallas_paged_kernel_matches_forward(greedy_vs_forward,
+                                                      monkeypatch):
+    # the Pallas tier's paged attention gets one layer's pools out of the
+    # stacked carry; the kernel runs under the interpreter (base-only
+    # projections stay one XLA GEMM on every tier)
+    from repro.kernels import paged_attention as kernel
+    compiled = kernel.paged_attention
+    monkeypatch.setattr(kernel, "paged_attention",
+                        lambda *a, **kw: compiled(*a, **kw, interpret=True))
+    dispatch.force_mode("pallas")
+    dispatch.reset_stats()
+    _run_static_vs_forward(greedy_vs_forward,
+                           _cfg(use_pallas=True, num_kv_heads=2),
+                           B=2, p=5, steps=6, chunk=3)
+    assert dispatch.stats["paged"] > 0, "paged kernel never engaged"
+
+
 def test_scheduled_churn_matches_forward(greedy_vs_forward):
     """Staggered completion: 6 requests through 2 engine slots — three
     waves recycling freed slots AND freed blocks.  Every request still
@@ -315,6 +337,94 @@ def test_scheduled_churn_matches_forward(greedy_vs_forward):
         assert len(r.tokens) == steps
         greedy_vs_forward(model, params, r.prompt, r.tokens,
                           bank.adapter(r.adapter_id))
+
+
+@pytest.mark.parametrize("kw", [
+    dict(num_kv_heads=2),
+    dict(attn_window=6),
+    dict(num_layers=4, block_pattern=("rglru", "attn")),
+    dict(num_layers=7, block_pattern=("mlstm", "attn", "slstm")),
+], ids=["gqa", "window", "rglru", "xlstm"])
+def test_scheduled_churn_reuses_the_donated_pool(kw, greedy_vs_forward,
+                                                 monkeypatch):
+    """Requests of several lengths through 2 slots: chunks end mid-request,
+    finished requests' blocks go to later admissions, and every decode
+    chunk donates the cache it is given.  Each cache a chunk took is
+    deleted after the call (the pool was written in place), none is read
+    again, and every request serves the forward's greedy tokens."""
+    build = serve._jit_paged_chunk
+    given = []
+
+    def recording(model):
+        fn = build(model)
+
+        def chunk(params, cache, *args, **kw):
+            given.append(cache)
+            return fn(params, cache, *args, **kw)
+        return chunk
+
+    monkeypatch.setattr(serve, "_jit_paged_chunk", recording)
+    cfg = _cfg(**kw)
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0))
+    steps = (7, 3, 9, 4, 6)
+    prompt = np.asarray(jax.random.randint(jax.random.key(8), (5, 5), 0,
+                                           cfg.vocab_size), np.int32)
+    reqs = [serve.Request(rid=i, prompt=prompt[i], steps=s)
+            for i, s in enumerate(steps)]
+    done = serve.serve_scheduled(model, params, reqs, max_batch=2,
+                                 block_size=2, chunk=3, max_len=14,
+                                 wait=False)
+    assert len(given) > 3
+    assert all(leaf.is_deleted()
+               for c in given for leaf in jax.tree.leaves(c))
+    for r in done:
+        assert len(r.tokens) == r.steps
+        greedy_vs_forward(model, params, r.prompt, r.tokens)
+
+
+def _pool_shapes(text, shapes):
+    """(opcode, shape) of every instruction in compiled HLO ``text`` whose
+    result has one of ``shapes`` (``"f32[3,9,3,2,16]"`` spelling)."""
+    found = []
+    for m in re.finditer(r"= (\w+\[[\d,]*\])(?:\{[^}]*\})? ([\w-]+)\(",
+                         text):
+        if m.group(1) in shapes:
+            found.append((m.group(2), m.group(1)))
+    return found
+
+
+def test_decode_chunk_writes_the_pool_in_place():
+    """The compiled decode chunk of a 3-layer GQA stack keeps its KV pool
+    in one buffer: no copy, broadcast or dynamic-update-slice has a pool
+    leaf's stacked shape (the step writes its rows by scatter into the
+    carried pool), and each pool parameter is aliased to an output."""
+    cfg = _cfg(num_heads=4, num_kv_heads=2)
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0))
+    b, mb, bs = 2, 4, 3
+    cache = model.init_paged_cache(1 + b * mb, bs, b)
+    assert {(a.dtype.name, a.shape) for a in jax.tree.leaves(cache)} == {
+        ("float32", (3, 9, 3, 2, 16)), ("int32", (3, 9, 3))}
+    pools = {"f32[3,9,3,2,16]", "s32[3,9,3]"}
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
+    text = serve._jit_paged_chunk(model).lower(
+        params, cache, i32(b, 1), i32(b), jnp.ones((b,), bool), i32(b, mb),
+        None, steps=4).compile().as_text()
+    moved = [op for op in _pool_shapes(text, pools)
+             if op[0] in ("copy", "broadcast", "dynamic-update-slice")]
+    assert not moved, moved
+    assert any(op == "scatter" for op, _ in _pool_shapes(text, pools))
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    params_at = {int(m.group(2)) for m in re.finditer(
+        r"= (\w+\[[\d,]*\])\{[^}]*\} parameter\((\d+)\)", entry)
+        if m.group(1) in pools}
+    assert len(params_at) == len(jax.tree.leaves(cache))
+    header = text[:text.index("\n")]
+    aliased = {int(n) for n in re.findall(r"\}: \((\d+), \{\}",
+                                          header)}
+    assert params_at <= aliased, (params_at, header[:300])
 
 
 def test_scheduled_mixed_lengths_and_steps_complete():

@@ -230,20 +230,56 @@ def test_generated_tokens_stay_in_vocab(greedy_vs_forward):
         greedy_vs_forward(model, params, prompt[i], toks)
 
 
-def test_scheduled_audio_family_completes():
+def _audio_cfg():
+    return _cfg(num_layers=2, family="audio", block_pattern=("xattn",),
+                encoder_layers=1, encoder_frames=4, encoder_d_model=32)
+
+
+def test_scheduled_audio_family_completes(greedy_vs_forward):
     """Encoder-decoder (xattn) stacks serve through the scheduler: admission
     without an encoder output keeps the cache's cross K/V — zeros, the
-    token-by-token path's semantics — instead of crashing.  ``Model.forward``
-    runs the encoder instead, so it is no reference here: the check is
-    completion and the real vocabulary."""
-    cfg = _cfg(num_layers=2, family="audio", block_pattern=("xattn",),
-               encoder_layers=1, encoder_frames=4, encoder_d_model=32)
+    token-by-token path's semantics — so the cross-attention adds nothing to
+    a served token.  With its output projection zeroed it adds nothing to
+    ``Model.forward`` either, which then checks the self-attention pools and
+    the cross K/V that the decode scan carries."""
+    cfg = _audio_cfg()
     model = build_model(cfg)
     params = model.init(jax.random.key(2))
+    cross = params["stack"]["repeat"]["p0"]["cross"]
+    cross["o"] = jnp.zeros_like(cross["o"])
+    frames = jnp.zeros((1, cfg.encoder_frames, cfg.d_model))
     prompt = jax.random.randint(jax.random.key(9), (2, 4), 0, 64)
-    for toks in _serve_rows(model, params, prompt, 4):
+    for i, toks in enumerate(_serve_rows(model, params, prompt, 4)):
         assert len(toks) == 4
-        assert all(0 <= t < cfg.vocab_size for t in toks)
+        greedy_vs_forward(model, params, prompt[i], toks, frames=frames)
+
+
+def test_decode_reads_each_layers_cross_kv(paged_cache):
+    """A prefill given the encoder output fills each layer's cross K/V, and
+    every decode step reads its own layer's from the carried cache: the
+    prefill's logits and the steps' after it are ``Model.forward``'s over
+    the same frames."""
+    cfg = _audio_cfg()
+    model = build_model(cfg)
+    params = model.init(jax.random.key(2))
+    b, p, k = 2, 4, 3
+    seq = jax.random.randint(jax.random.key(11), (b, p + k), 0, 64)
+    frames = jax.random.normal(jax.random.key(12),
+                               (b, cfg.encoder_frames, cfg.d_model))
+    full, _ = model.forward(params, {"tokens": seq, "frames": frames})
+    cache, table = paged_cache(model, b, p + k)
+    enc_out = model._encode(params, {"frames": frames})
+    logits, cache = model.prefill(params, cache, seq[:, :p], table=table,
+                                  enc_out=enc_out)
+    step = jax.jit(lambda c, t, pos: model.decode_step(params, c, t, pos,
+                                                       table=table))
+    outs = [logits]
+    for t in range(p, p + k):
+        lg, cache = step(cache, seq[:, t:t + 1], jnp.full((b,), t))
+        outs.append(lg)
+    np.testing.assert_allclose(np.asarray(full),
+                               np.asarray(jnp.concatenate(outs, axis=1)),
+                               rtol=1e-5, atol=1e-5)
 
 
 # ----------------------------------------------------------------- the CLI

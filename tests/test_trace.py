@@ -217,6 +217,9 @@ def test_serve_scheduled_spans_counters_and_first_token_stamp(monkeypatch):
     assert t.counters["serve.admitted"] == len(done)
     assert t.counters["serve.dispatches"] == len(admits) + len(chunks)
     assert t.counters["serve.decode_slot_steps"] == 2 * 2 * len(chunks)
+    # recorded once, when the decode chunk is traced: both scanned
+    # attention layers write their pool in place
+    assert t.counters["serve.kv_inplace_layers"] == cfg.num_layers == 2
 
 
 def test_trainer_chunk_spans_hold_their_four_steps():
